@@ -310,8 +310,9 @@ def uncertainty_head_fused_kernel(x: jax.Array, mu: jax.Array,
     assert m % bm == 0, (m, bm)
     v_pad = (-v) % bv
     if v_pad:
-        mu = jnp.pad(mu, ((0, 0), (0, v_pad)))
-        sigma = jnp.pad(sigma, ((0, 0), (0, v_pad)))
+        with jax.named_scope("vocab_pad"):
+            mu = jnp.pad(mu, ((0, 0), (0, v_pad)))
+            sigma = jnp.pad(sigma, ((0, 0), (0, v_pad)))
     vp = v + v_pad
     grid = (m // bm, vp // bv)
     in_kernel_rng = xi is None

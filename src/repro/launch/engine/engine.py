@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 from typing import Optional
 
 import jax
@@ -415,13 +414,26 @@ class ServeEngine:
         lens0 = np.zeros((self.num_slots,), np.int32)
         for slot, req in parts:
             lens0[slot] = len(req.prompt) + len(req.tokens)
-        t0 = time.perf_counter()
-        tok, cache, dys = draft_fn(self.params, tok, cache)
-        vys = verify_fn(self.params, dys["hidden"],
-                        runner.put_replicated(jnp.asarray(lens0)))
-        host = jax.device_get({"draft": dys["token"], **vys})
-        stats.arrivals.append(time.perf_counter())
-        stats.decode_s += time.perf_counter() - t0
+        with stats.span("serve.spec_round"):
+            stats.dispatched(len(parts))
+            tok, cache, dys = draft_fn(self.params, tok, cache)
+            vys = verify_fn(self.params, dys["hidden"],
+                            runner.put_replicated(jnp.asarray(lens0)))
+            out = jax.copy_to_host_async({"draft": dys["token"], **vys})
+        with stats.span("serve.decode_wait"):
+            jax.block_until_ready(out)
+        with stats.span("serve.decode_fetch"):
+            host = jax.device_get(out)
+        with stats.span("serve.harvest"):
+            return self._spec_harvest(sched, stats, decoding, parts, k,
+                                      lens0, host, dys, tok, cache,
+                                      active, flags, escalate)
+
+    def _spec_harvest(self, sched, stats, decoding, parts, k, lens0, host,
+                      dys, tok, cache, active, flags, escalate):
+        """Keep each slot's agreeing prefix of a speculative round's
+        drafts plus the verified correction, then commit on device."""
+        runner = self.runner
         stats.spec_rounds += 1
         stats.full_model_calls += 1          # ONE batched verify
         stats.steps_run += k
@@ -630,12 +642,14 @@ class ServeEngine:
 
         def activate(slot, req):
             nonlocal tok, active, flags
-            req.transition("decoding")
-            req.spec_k_cur = self.spec_k
-            tok = tok.at[slot].set(int(req.prompt[-1]))
-            active = active.at[slot].set(True)
-            flags = {k: v.at[slot].set(0) for k, v in flags.items()}
-            decoding.add(slot)
+            # four small device updates, each its own dispatch
+            with stats.span("serve.activate"):
+                req.transition("decoding")
+                req.spec_k_cur = self.spec_k
+                tok = tok.at[slot].set(int(req.prompt[-1]))
+                active = active.at[slot].set(True)
+                flags = {k: v.at[slot].set(0) for k, v in flags.items()}
+                decoding.add(slot)
 
         def sync_table():
             # re-upload the device block table (tiny: slots x MB) only
@@ -647,9 +661,136 @@ class ServeEngine:
                     sched.block_tables))
                 table_synced = sched.table_version
 
-        try:
-            while sched.has_work() or pending \
-                    or (lane is not None and lane.has_work()):
+        def admit_one(slot, req):
+            """Map one admission's prompt: a prefix hit, a queued
+            chunked walk, or a batch prefill (``serve.prefill``)."""
+            nonlocal cache
+            info = sched.prefix_admit(slot) if paged else None
+            hit_len = info.tokens if info is not None else 0
+            P = len(req.prompt)
+            W = self._bucket(P)
+            if info is not None and info.cow is not None:
+                # the shared tail block is about to be written at the
+                # divergence point: duplicate it device-side and let the
+                # scheduler drop this slot's ref on the original
+                src, dst = info.cow
+                cache = self._copy(cache, jnp.asarray(src, jnp.int32),
+                                   jnp.asarray(dst, jnp.int32))
+                sched.finish_cow(slot)
+                stats.pc_cow += 1
+            slot_ = jnp.asarray(slot, jnp.int32)
+            if info is not None:
+                stats.record_admission(P, hit_len)
+            if self.prefill_mode == "chunked" and hit_len < P:
+                # enqueue an incremental prompt walk (suffix-only on a
+                # partial prefix hit — CoW already settled above) and
+                # pin the slot's depth to the resident span NOW:
+                # interleaved scans write junk at [len, len+chunk) for
+                # every slot, and a stale len would point into shared
+                # prefix blocks
+                cache = self._set_len(cache, slot_,
+                                      jnp.asarray(hit_len, jnp.int32))
+                prefilling[slot] = self._start_job(req, hit_len, W, cache)
+                jobs.append(slot)
+                return
+            with stats.span("serve.prefill") as sp:
+                if hit_len == P:
+                    # whole prompt resident: zero prefill compute — the
+                    # decode loop only needs the slot's depth
+                    cache = self._set_len(cache, slot_,
+                                          jnp.asarray(P, jnp.int32))
+                    shape_key = ("hit",)
+                elif hit_len > 0:
+                    # suffix padded to the same bucketed span the cold
+                    # path reduces over (W - hit junk tokens): equal
+                    # extents keep hit and cold bit-identical
+                    stoks = np.zeros((W - hit_len,), np.int32)
+                    stoks[:P - hit_len] = req.prompt[hit_len:]
+                    cache = self._suffix(
+                        self.params, cache, slot_,
+                        runner.place_table(sched.block_tables[slot]),
+                        jnp.asarray(stoks)[None], hit_len)
+                    if W > P:
+                        cache = self._set_len(cache, slot_,
+                                              jnp.asarray(P, jnp.int32))
+                    shape_key = ("suffix", hit_len, W - hit_len)
+                else:
+                    toks = np.zeros((W,), np.int32)
+                    toks[:P] = req.prompt
+                    _, sub = self._prefill(self.params,
+                                           jnp.asarray(toks)[None],
+                                           modality1)
+                    if paged:
+                        cache = self._write(
+                            cache, slot_, sub,
+                            runner.place_table(sched.block_tables[slot]))
+                    else:
+                        cache = self._write(cache, slot_, sub)
+                    if W > P:
+                        # junk pad KV stays masked above the true len
+                        cache = self._set_len(cache, slot_,
+                                              jnp.asarray(P, jnp.int32))
+                    shape_key = ("cold", W)
+                activate(slot, req)
+            with stats.span("serve.prefill_wait") as wt:
+                jax.block_until_ready(cache)
+            stats.classify(shape_key, sp.s + wt.s)
+
+        def tally_attn_blocks(ahead):
+            # the gather path materializes every slot's full logical
+            # span each step, occupied or not (a spec round's draft
+            # reads decode attention for its k steps exactly like k
+            # scan steps; the verify is head-only and touches no KV)
+            MB = sched.block_tables.shape[1]
+            stats.attn_blocks_span += self.num_slots * MB * ahead
+            if self.decode_attn == "kernel":
+                # the kernel reads only mapped blocks under each
+                # occupied slot's depth
+                for slot, occupant in sched.active():
+                    if slot in prefilling:
+                        continue
+                    len0 = len(occupant.prompt) + len(occupant.tokens)
+                    mapped = sched.mapped_blocks(slot)
+                    stats.attn_blocks_read += sum(
+                        kv_blocks_read(len0 + t + 1, mapped,
+                                       self.kv_block, MB)
+                        for t in range(ahead))
+
+        def harvest(ys):
+            nonlocal active
+            for slot, req in sched.active():
+                if slot in prefilling:
+                    continue             # mid-prefill: junk steps, no harvest
+                finished = False
+                for t in range(self.chunk):
+                    tk = int(ys["token"][t, slot])
+                    req.tokens.append(tk)
+                    for name in ("H", "SE", "MI", "p_max"):
+                        getattr(req, name).append(float(ys[name][t, slot]))
+                    req.epistemic_flags += int(ys["epistemic"][t, slot])
+                    req.aleatoric_flags += int(ys["aleatoric"][t, slot])
+                    req.last_mi = float(ys["MI"][t, slot])
+                    done_eos = self.eos_id is not None and tk == self.eos_id
+                    if done_eos or len(req.tokens) >= req.max_new_tokens:
+                        req.transition(
+                            "finished",
+                            reason="eos" if done_eos else "length")
+                        sched.evict(slot)
+                        decoding.discard(slot)
+                        active = active.at[slot].set(False)
+                        finished = True
+                        break
+                # escalation check on the slot's CARRIED (chunk-end)
+                # MI: unfinished flagged slots finish on the lane
+                if not finished and maybe_escalate(slot, req):
+                    active = active.at[slot].set(False)
+
+        def iterate():
+            """One pass of the engine loop: admissions, at most one
+            prompt chunk, grants, one unit of lane work, then one decode
+            chunk (or speculative round) and its harvest."""
+            nonlocal tok, cache, active, flags, step0
+            with stats.span("serve.admit"):
                 # fire every arrival whose step has come; when the
                 # engine is otherwise idle, fast-forward to the next
                 # arrival group instead of spinning on empty iterations
@@ -665,10 +806,10 @@ class ServeEngine:
                         sched.submit(pending.popleft())
                         fired += 1
                 admitted = sched.admit()
-                # admission-pressure preemptions (priority policy):
-                # the victims' requests are already requeued; drop the
-                # slots from the engine's decode set before the new
-                # admissions (possibly into those slots) re-arm them
+                # admission-pressure preemptions (priority policy): the
+                # victims' requests are already requeued; drop the slots
+                # from the engine's decode set before the new admissions
+                # (possibly into those slots) re-arm them
                 for slot, _req in sched.take_preempted():
                     decoding.discard(slot)
                     active = active.at[slot].set(False)
@@ -678,102 +819,33 @@ class ServeEngine:
                     # prefill write installs a row at the new width
                     sync_table()
                 for slot, req in admitted:
-                    t0 = time.perf_counter()
-                    info = sched.prefix_admit(slot) if paged else None
-                    hit_len = info.tokens if info is not None else 0
-                    P = len(req.prompt)
-                    W = self._bucket(P)
-                    if info is not None and info.cow is not None:
-                        # the shared tail block is about to be written at the
-                        # divergence point: duplicate it device-side and let
-                        # the scheduler drop this slot's ref on the original
-                        src, dst = info.cow
-                        cache = self._copy(cache, jnp.asarray(src, jnp.int32),
-                                           jnp.asarray(dst, jnp.int32))
-                        sched.finish_cow(slot)
-                        stats.pc_cow += 1
-                    slot_ = jnp.asarray(slot, jnp.int32)
-                    shape_key: Optional[tuple] = None
-                    if hit_len == P:
-                        # whole prompt resident: zero prefill compute — the
-                        # decode loop only needs the slot's depth
-                        cache = self._set_len(cache, slot_,
-                                              jnp.asarray(P, jnp.int32))
-                        shape_key = ("hit",)
-                        activate(slot, req)
-                    elif self.prefill_mode == "chunked":
-                        # enqueue an incremental prompt walk (suffix-only
-                        # on a partial prefix hit — CoW already settled
-                        # above) and pin the slot's depth to the resident
-                        # span NOW: interleaved scans write junk at
-                        # [len, len+chunk) for every slot, and a stale
-                        # len would point into shared prefix blocks
-                        cache = self._set_len(
-                            cache, slot_, jnp.asarray(hit_len, jnp.int32))
-                        prefilling[slot] = self._start_job(req, hit_len, W,
-                                                           cache)
-                        jobs.append(slot)
-                    elif hit_len > 0:
-                        # suffix padded to the same bucketed span the
-                        # cold path reduces over (W - hit junk tokens):
-                        # equal extents keep hit and cold bit-identical
-                        stoks = np.zeros((W - hit_len,), np.int32)
-                        stoks[:P - hit_len] = req.prompt[hit_len:]
-                        cache = self._suffix(
-                            self.params, cache, slot_,
-                            runner.place_table(sched.block_tables[slot]),
-                            jnp.asarray(stoks)[None], hit_len)
-                        if W > P:
-                            cache = self._set_len(
-                                cache, slot_, jnp.asarray(P, jnp.int32))
-                        shape_key = ("suffix", hit_len, W - hit_len)
-                        activate(slot, req)
-                    else:
-                        toks = np.zeros((W,), np.int32)
-                        toks[:P] = req.prompt
-                        _, sub = self._prefill(
-                            self.params, jnp.asarray(toks)[None],
-                            modality1)
-                        if paged:
-                            cache = self._write(
-                                cache, slot_, sub,
-                                runner.place_table(sched.block_tables[slot]))
-                        else:
-                            cache = self._write(cache, slot_, sub)
-                        if W > P:
-                            # junk pad KV stays masked above the true len
-                            cache = self._set_len(
-                                cache, slot_, jnp.asarray(P, jnp.int32))
-                        shape_key = ("cold", W)
-                        activate(slot, req)
-                    if info is not None:
-                        stats.record_admission(P, hit_len)
-                    if shape_key is not None:
-                        jax.block_until_ready(cache)
-                        stats.classify(shape_key, time.perf_counter() - t0)
+                    admit_one(slot, req)
 
-                if jobs:
-                    # at most ONE prompt chunk per engine iteration
-                    # (Sarathi-style): the head walk advances by
-                    # prefill_chunk tokens, then the decode scan below
-                    # still runs for every active slot
-                    slot = jobs[0]
-                    job = prefilling[slot]
-                    req = job["req"]
-                    t0 = time.perf_counter()
+            if jobs:
+                # at most ONE prompt chunk per engine iteration
+                # (Sarathi-style): the head walk advances by
+                # prefill_chunk tokens, then the decode scan below still
+                # runs for every active slot
+                slot = jobs[0]
+                job = prefilling[slot]
+                req = job["req"]
+                with stats.span("serve.prefill_chunk") as sp:
+                    req.prefill_started(sp.t0)
                     cache, done, shape_key = self._run_chunk(cache, slot,
                                                              job)
                     stats.prefill_chunks += 1
+                with stats.span("serve.prefill_wait") as wt:
                     jax.block_until_ready(cache)
-                    stats.classify(shape_key, time.perf_counter() - t0)
-                    if done:
-                        jobs.popleft()
-                        del prefilling[slot]
-                        # activate BEFORE this iteration's scan: the
-                        # slot's first real decode tokens come from it
-                        # (no junk window between prefill and decode)
-                        activate(slot, req)
+                stats.classify(shape_key, sp.s + wt.s)
+                if done:
+                    jobs.popleft()
+                    del prefilling[slot]
+                    # activate BEFORE this iteration's scan: the slot's
+                    # first real decode tokens come from it (no junk
+                    # window between prefill and decode)
+                    activate(slot, req)
 
+            with stats.span("serve.grant"):
                 # a speculative round replaces this iteration's scan
                 # chunk when ANY decoding slot's carried MI sits strictly
                 # below the gate (threshold 0 therefore never drafts and
@@ -805,93 +877,68 @@ class ServeEngine:
                                                 req.max_new_tokens))
                         if ids is None:
                             # the pool cannot grow this slot even after
-                            # LRU-evicting cached blocks: preempt — blocks
-                            # release, the lifecycle transition clears the
-                            # output, the request restarts from the queue
-                            # FRONT
+                            # LRU-evicting cached blocks: preempt —
+                            # blocks release, the lifecycle transition
+                            # clears the output, the request restarts
+                            # from the queue FRONT
                             sched.preempt(slot)
                             decoding.discard(slot)
                             active = active.at[slot].set(False)
                     sync_table()
-
+                    if decoding:
+                        tally_attn_blocks(ahead)
                 stats.trace(sched)
-                # ONE unit of lane work per iteration (admission or a
-                # chunk at the verify S): escalations drain alongside
-                # the main pool without stalling its decode cadence
-                lane_ran = lane.step(stats) if lane is not None else False
-                if not decoding:
-                    if not jobs and not admitted and not lane_ran \
-                            and not fired:
-                        raise RuntimeError(
-                            "scheduler stalled: queued requests, no "
-                            "admission, nothing prefilling or decoding")
-                    continue             # prefill-only iteration: no scan
-                if paged:
-                    MB = sched.block_tables.shape[1]
-                    # the gather path materializes every slot's full
-                    # logical span each step, occupied or not (a spec
-                    # round's draft reads decode attention for its k
-                    # steps exactly like k scan steps; the verify is
-                    # head-only and touches no KV)
-                    stats.attn_blocks_span += self.num_slots * MB * ahead
-                    if self.decode_attn == "kernel":
-                        # the kernel reads only mapped blocks under
-                        # each occupied slot's depth
-                        for slot, occupant in sched.active():
-                            if slot in prefilling:
-                                continue
-                            len0 = len(occupant.prompt) \
-                                + len(occupant.tokens)
-                            mapped = sched.mapped_blocks(slot)
-                            stats.attn_blocks_read += sum(
-                                kv_blocks_read(len0 + t + 1, mapped,
-                                               self.kv_block, MB)
-                                for t in range(ahead))
 
-                if run_spec:
-                    tok, cache, active, flags = self._spec_round(
-                        sched, stats, decoding, tok, cache, active, flags,
-                        k=k_round, escalate=maybe_escalate)
-                    continue
+            # ONE unit of lane work per iteration (admission or a chunk
+            # at the verify S): escalations drain alongside the main
+            # pool without stalling its decode cadence
+            lane_ran = False
+            if lane is not None:
+                with stats.span("serve.escalate"):
+                    lane_ran = lane.step(stats)
+            if not decoding:
+                if not jobs and not admitted and not lane_ran \
+                        and not fired:
+                    raise RuntimeError(
+                        "scheduler stalled: queued requests, no "
+                        "admission, nothing prefilling or decoding")
+                return                   # prefill-only iteration: no scan
 
-                stats.chunks_run += 1
-                stats.full_model_calls += self.chunk
-                stats.steps_run += self.chunk
-                t0 = time.perf_counter()
+            if run_spec:
+                tok, cache, active, flags = self._spec_round(
+                    sched, stats, decoding, tok, cache, active, flags,
+                    k=k_round, escalate=maybe_escalate)
+                return
+
+            stats.chunks_run += 1
+            stats.full_model_calls += self.chunk
+            stats.steps_run += self.chunk
+            with stats.span("serve.decode_dispatch"):
+                stats.dispatched(len(decoding))
                 tok, cache, flags, ys = self._scan(
                     self.params, tok, cache, jnp.asarray(step0, jnp.int32),
                     active, flags)
-                ys = jax.device_get(ys)            # the chunk's single sync
-                stats.arrivals.append(time.perf_counter())
-                stats.decode_s += time.perf_counter() - t0
-                step0 += self.chunk
+                # queue the outputs' copies to the host behind the scan
+                # (what device_get starts with), so that they run as
+                # the device finishes and not once the host has woken
+                jax.copy_to_host_async(ys)
+            # the chunk's single sync, split into the wait for the
+            # device and the rest of the copy of its outputs
+            with stats.span("serve.decode_wait"):
+                jax.block_until_ready(ys)
+            with stats.span("serve.decode_fetch"):
+                ys = jax.device_get(ys)
+            step0 += self.chunk
+            with stats.span("serve.harvest"):
+                harvest(ys)
 
-                for slot, req in sched.active():
-                    if slot in prefilling:
-                        continue         # mid-prefill: junk steps, no harvest
-                    finished = False
-                    for t in range(self.chunk):
-                        tk = int(ys["token"][t, slot])
-                        req.tokens.append(tk)
-                        for name in ("H", "SE", "MI", "p_max"):
-                            getattr(req, name).append(float(ys[name][t, slot]))
-                        req.epistemic_flags += int(ys["epistemic"][t, slot])
-                        req.aleatoric_flags += int(ys["aleatoric"][t, slot])
-                        req.last_mi = float(ys["MI"][t, slot])
-                        done_eos = self.eos_id is not None and tk == self.eos_id
-                        if done_eos or len(req.tokens) >= req.max_new_tokens:
-                            req.transition(
-                                "finished",
-                                reason="eos" if done_eos else "length")
-                            sched.evict(slot)
-                            decoding.discard(slot)
-                            active = active.at[slot].set(False)
-                            finished = True
-                            break
-                    # escalation check on the slot's CARRIED (chunk-end)
-                    # MI: unfinished flagged slots finish on the lane
-                    if not finished and maybe_escalate(slot, req):
-                        active = active.at[slot].set(False)
+        try:
+            it = 0
+            while sched.has_work() or pending \
+                    or (lane is not None and lane.has_work()):
+                with stats.iteration(it):
+                    iterate()
+                it += 1
 
         except BaseException:
             # eviction / exception / early-exit path: slots mid-decode

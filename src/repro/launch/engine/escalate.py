@@ -27,7 +27,6 @@ Tested in tests/test_policy.py::TestEscalation.
 from __future__ import annotations
 
 import collections
-import time
 
 import jax
 import jax.numpy as jnp
@@ -120,14 +119,15 @@ class EscalationLane:
         """One scan chunk at the verify S, harvested into the request."""
         r = self.runner
         req = self.current
-        t0 = time.perf_counter()
-        self._tok, self._cache, self._flags, ys = r._scan(
-            r.params, self._tok, self._cache,
-            jnp.asarray(self._step0, jnp.int32), self._active, self._flags)
-        ys = jax.device_get(ys)
-        dt = time.perf_counter() - t0
-        stats.esc_decode_s += dt
-        stats.decode_s += dt
+        with stats.span("serve.escalate_decode"):
+            self._tok, self._cache, self._flags, ys = r._scan(
+                r.params, self._tok, self._cache,
+                jnp.asarray(self._step0, jnp.int32), self._active,
+                self._flags)
+            jax.copy_to_host_async(ys)
+            with stats.span("serve.escalate_wait"):
+                jax.block_until_ready(ys)
+            ys = jax.device_get(ys)
         stats.esc_steps += self.chunk
         self._step0 += self.chunk
         for t in range(self.chunk):

@@ -88,6 +88,12 @@ class Request:
     # wall time spent waiting in the queue (accumulates across preempt
     # re-entries); latency_s - queue_time_s is the service time
     queue_time_s: float = 0.0
+    # wall time between admission to a slot and the dispatch of that
+    # admission's first prefill chunk: the wait in the chunked-prefill
+    # queue behind other prompts (accumulates across preempt re-entries;
+    # 0 on the prefix-hit and batch-prefill paths, which prefill at
+    # admission)
+    prefill_wait_s: float = 0.0
     preempt_count: int = 0
     # submission order (assigned once by the scheduler); the priority
     # policy's final tie-break, so equal-priority traffic stays FIFO
@@ -97,13 +103,18 @@ class Request:
     spec_k_cur: int = 0
     spec_ema: Optional[float] = None
     _t_queued: float = dataclasses.field(default=0.0, repr=False)
+    # admission time while the prefill-queue clock runs (None: closed)
+    _t_admitted: Optional[float] = dataclasses.field(default=None,
+                                                     repr=False)
 
     def transition(self, to: str, *, reason: str = "") -> None:
         """THE audited lifecycle edge — every state change funnels
         through here.  Raises on an illegal move; applies the edge's
         side effects exactly once: ``queued`` stamps t_submit (first
         entry) and opens the queue-wait clock, ``prefilling`` closes
-        it into queue_time_s, ``preempted`` clears the accumulated
+        it into queue_time_s and opens the prefill-queue clock
+        (``prefill_started`` closes that; ``decoding`` drops it
+        uncounted), ``preempted`` clears the accumulated
         output (re-admission replays from the prompt) and resets the
         spec-decode EMA, ``finished`` stamps t_finish/finish_reason."""
         if to not in LIFECYCLE[self.state]:
@@ -118,7 +129,14 @@ class Request:
             self._t_queued = now
         elif to == "prefilling":
             self.queue_time_s += now - self._t_queued
+            self._t_admitted = now
+        elif to == "decoding":
+            self._t_admitted = None
         elif to == "preempted":
+            if self._t_admitted is not None:
+                # preempted while still waiting for its first chunk
+                self.prefill_wait_s += now - self._t_admitted
+                self._t_admitted = None
             self.preempt_count += 1
             self.tokens.clear()
             for name in ("H", "SE", "MI", "p_max"):
@@ -133,6 +151,13 @@ class Request:
             self.finish_reason = reason
         self.state = to
         self.history.append((to, now))
+
+    def prefill_started(self, t: float) -> None:
+        """Close the prefill-queue clock at ``t``, the dispatch of this
+        admission's first prefill chunk, into prefill_wait_s."""
+        if self._t_admitted is not None:
+            self.prefill_wait_s += t - self._t_admitted
+            self._t_admitted = None
 
     @property
     def latency_s(self) -> float:

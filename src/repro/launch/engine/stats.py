@@ -2,21 +2,74 @@
 
 ``ServeStats`` owns every counter the engine run accumulates — prefill
 compile-vs-steady classification, prefix-cache hit accounting, the
-decode-attention HBM block tally, the downsampled scheduler trace,
-decode-chunk arrival timestamps — and builds the results dict
-``ServeEngine.run`` returns.  Pure host-side: nothing here touches jax
-beyond ``device_flag_counters`` reading back the scan carry the engine
-hands it.
+decode-attention HBM block tally, the downsampled scheduler trace —
+plus the span recorder the engine loop runs inside, and builds the
+results dict ``ServeEngine.run`` returns.
+
+Spans: ``span(name)`` marks one phase of the loop and ``iteration(i)``
+one pass of it.  Each enters a ``jax.profiler`` annotation (a host
+event on the device trace's clock whenever a trace is running; about
+a microsecond when none is) and stamps ``time.perf_counter`` into
+per-phase totals (seconds, count, longest).  Each iteration appends
+one ``IterationRecord`` to a bounded deque.  The names are in
+docs/serving.md ("Spans and counters").
 """
 
 from __future__ import annotations
 
+import collections
 import time
 from collections import Counter
+from typing import NamedTuple
 
+import jax
 import numpy as np
 
 from repro.models import registry as M
+
+ITERATION = "serve.iteration"
+# phases in which the host only waits for the device
+WAITS = ("serve.prefill_wait", "serve.decode_wait", "serve.escalate_wait")
+# phases whose time is decode: the main scan's dispatch, wait and
+# fetch, a speculative round's dispatch, the escalation lane's chunks
+DECODE = ("serve.decode_dispatch", "serve.decode_wait",
+          "serve.decode_fetch", "serve.spec_round", "serve.escalate_decode")
+# iteration records kept: about 21 minutes of the ~310 ms iterations of
+# a 24-slot qwen2-1.5B engine on one TPU v5e, 4096 small tuples
+ITERATIONS_KEPT = 4096
+
+
+class IterationRecord(NamedTuple):
+    """One pass of the engine loop: its start and end on
+    ``time.perf_counter``, the seconds inside it spent in ``WAITS``, and
+    the slots decoding in the scan (or speculative round) it dispatched
+    (0 when it dispatched none)."""
+
+    t0: float
+    t1: float
+    wait_s: float
+    rows: int
+
+
+class Span:
+    """One timed phase: a ``jax.profiler.TraceAnnotation`` plus host
+    stamps; ``t0`` and ``s`` (its duration) are set as it runs."""
+
+    __slots__ = ("_stats", "_ann", "name", "t0", "s")
+
+    def __init__(self, stats: "ServeStats", name: str, ann):
+        self._stats, self._ann, self.name = stats, ann, name
+        self.t0 = self.s = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.s = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        self._stats._close(self)
 
 
 def _pcts(values) -> tuple[float, float]:
@@ -32,22 +85,30 @@ class ServeStats:
 
     The engine mutates the counter attributes directly inside its chunk
     loop (they are the same names the monolithic loop used as locals);
-    ``classify`` splits each prefill dispatch into compile-vs-steady by
-    whether its shape key was seen before, ``trace`` appends the
-    scheduler/pool snapshot every ``trace_every``-th chunk, and
-    ``results`` assembles the full metrics payload.
+    ``span``/``iteration`` time its phases, ``classify`` splits each
+    prefill dispatch into compile-vs-steady by whether its shape key was
+    seen before, ``trace`` appends the scheduler/pool snapshot every
+    ``trace_every``-th chunk, and ``results`` assembles the full metrics
+    payload.
     """
 
     def __init__(self, *, trace_every: int):
         self.trace_every = trace_every
         self.t_start = time.perf_counter()
-        self.decode_s = 0.0
+        # span recorder: per-phase [seconds, count, longest], the
+        # iteration records, and the open iteration's wait and rows
+        self.phases: dict[str, list] = {}
+        self.iterations: collections.deque = collections.deque(
+            maxlen=ITERATIONS_KEPT)
+        self._iter_wait = 0.0
+        self._iter_rows = 0
         # the jitted prefill compiles once per distinct prompt length
         # (suffix prefill: per distinct suffix length); classify each
         # admission's time accordingly so mixed-length traffic doesn't
         # launder recompiles into the steady-state stat
-        self.compile_times: list[float] = []
-        self.steady_times: list[float] = []
+        self.compile_s = 0.0
+        self.steady_s = 0.0
+        self.steady_n = 0
         self.seen_prefill_shapes: set[tuple] = set()
         # prefix-cache counters + per-chunk scheduler/pool trace
         self.pc_hits = self.pc_misses = self.pc_cow = 0
@@ -69,7 +130,6 @@ class ServeStats:
         self.esc_by_class: Counter = Counter()
         self.esc_tokens = 0
         self.esc_skipped = 0
-        self.esc_decode_s = 0.0
         self.esc_steps = 0
         # adaptive spec-decode depth: per-slot EMA grow/shrink events
         # and the range of round depths actually drafted
@@ -91,18 +151,47 @@ class ServeStats:
         self.spec_gated = 0
         self.full_model_calls = 0
         self.steps_run = 0
-        # decode-token inter-arrival: one timestamp per scan that served
-        # at least one decoding slot — the stall a long batch prefill
-        # injects between consecutive chunks is exactly what chunked
-        # prefill bounds (decode_interarrival_p99_s)
-        self.arrivals: list[float] = []
+
+    def span(self, name: str) -> Span:
+        """``with stats.span("serve.harvest"):`` times one phase."""
+        return Span(self, name, jax.profiler.TraceAnnotation(name))
+
+    def iteration(self, i: int) -> Span:
+        """``with stats.iteration(i):`` around one pass of the engine
+        loop: a step annotation on the trace, an ``IterationRecord``."""
+        self._iter_wait = 0.0
+        self._iter_rows = 0
+        return Span(self, ITERATION, jax.profiler.StepTraceAnnotation(
+            ITERATION, step_num=i))
+
+    def dispatched(self, rows: int) -> None:
+        """The open iteration's decode dispatch serves ``rows`` slots."""
+        self._iter_rows = rows
+
+    def _close(self, sp: Span) -> None:
+        tot = self.phases.get(sp.name)
+        if tot is None:
+            tot = self.phases[sp.name] = [0.0, 0, 0.0]
+        tot[0] += sp.s
+        tot[1] += 1
+        tot[2] = max(tot[2], sp.s)
+        if sp.name in WAITS:
+            self._iter_wait += sp.s
+        elif sp.name == ITERATION:
+            self.iterations.append(IterationRecord(
+                sp.t0, sp.t0 + sp.s, self._iter_wait, self._iter_rows))
+
+    def phase_s(self, *names: str) -> float:
+        """Total seconds recorded under ``names``."""
+        return sum(self.phases[n][0] for n in names if n in self.phases)
 
     def classify(self, shape_key: tuple, dt: float) -> None:
         if shape_key in self.seen_prefill_shapes:
-            self.steady_times.append(dt)
+            self.steady_s += dt
+            self.steady_n += 1
         else:
             self.seen_prefill_shapes.add(shape_key)
-            self.compile_times.append(dt)
+            self.compile_s += dt
 
     def record_round_k(self, k: int) -> None:
         """Track the range of draft depths adaptive-k rounds used."""
@@ -131,6 +220,7 @@ class ServeStats:
         plus the terminal scheduler / allocator / cache state."""
         paged = engine.kv_layout == "paged"
         total_s = time.perf_counter() - self.t_start
+        decode_s = self.phase_s(*DECODE)
         gen_tokens = sum(len(r.tokens) for r in requests)
         # KV residency accounting: dense permanently owns num_slots
         # strips of max_len; paged owns only the blocks actually mapped
@@ -195,6 +285,7 @@ class ServeStats:
                 "escalations": sum(r.was_escalated for r in group),
                 "preemptions": sum(r.preempt_count for r in group),
             }
+        served = [it.t1 for it in self.iterations if it.rows]
         epi = sum(r.epistemic_flags for r in requests)
         alea = sum(r.aleatoric_flags for r in requests)
         return {
@@ -202,13 +293,13 @@ class ServeStats:
             "num_requests": len(requests),
             "gen_tokens": gen_tokens,
             "total_s": total_s,
-            "decode_s": self.decode_s,
+            "decode_s": decode_s,
             # first prefill per prompt length includes compilation; the
             # rest are steady-state dispatch
-            "prefill_compile_s": float(np.sum(self.compile_times)),
-            "prefill_steady_s": float(np.mean(self.steady_times))
-            if self.steady_times else 0.0,
-            "decode_tok_per_s": gen_tokens / max(self.decode_s, 1e-9),
+            "prefill_compile_s": self.compile_s,
+            "prefill_steady_s": self.steady_s / self.steady_n
+            if self.steady_n else 0.0,
+            "decode_tok_per_s": gen_tokens / max(decode_s, 1e-9),
             "e2e_tok_per_s": gen_tokens / max(total_s, 1e-9),
             "latency_p50_s": float(np.percentile(lat, 50)),
             # nearest-rank (no interpolation): at small N a linear-
@@ -275,7 +366,7 @@ class ServeStats:
                 "by_class": dict(self.esc_by_class),
                 "tokens": self.esc_tokens,
                 "skipped_too_long": self.esc_skipped,
-                "decode_s": self.esc_decode_s,
+                "decode_s": self.phase_s("serve.escalate_decode"),
                 "steps": self.esc_steps,
             },
             # uncertainty-gated speculative decoding: acceptance per
@@ -309,12 +400,21 @@ class ServeStats:
                 "round_k_min": self.spec_round_k_min,
                 "round_k_max": self.spec_round_k_max,
             },
-            # worst gap between consecutive decode-serving scans: the
-            # stall a monolithic batch prefill injects mid-stream, which
-            # interleaved chunked prefill bounds at ~one chunk's compute
+            # worst gap between consecutive decode-serving iterations
+            # (over the records kept): the stall a monolithic batch
+            # prefill injects mid-stream, which interleaved chunked
+            # prefill bounds at ~one chunk's compute
             "decode_interarrival_p99_s": float(np.percentile(
-                np.diff(self.arrivals), 99, method="higher"))
-            if len(self.arrivals) >= 2 else 0.0,
+                np.diff(served), 99, method="higher"))
+            if len(served) >= 2 else 0.0,
+            # the span recorder: per-phase totals over the whole run and
+            # the last ITERATIONS_KEPT iteration records
+            "spans": {
+                "phases": {n: {"s": t[0], "count": t[1], "max_s": t[2]}
+                           for n, t in self.phases.items()},
+                "iterations": list(self.iterations),
+                "iterations_kept": self.iterations.maxlen,
+            },
             "epistemic_flags": int(epi),
             "aleatoric_flags": int(alea),
             "flags_per_1k_tokens": {

@@ -210,7 +210,8 @@ def build_scan_decode(cfg: ArchConfig, entropy=None, chunk: int = 8,
         def body(carry, t):
             tok, cache, epi, alea = carry
             key = jax.random.fold_in(base, step0 + t) if fold else base
-            out, cache = M.decode_step(params, cfg, tok, cache, key)
+            with jax.named_scope("decode_step"):
+                out, cache = M.decode_step(params, cfg, tok, cache, key)
             is_epi = out["MI"] > mi_threshold
             is_alea = (out["SE"] > se_threshold) & ~is_epi
             ys = {"token": out["next_token"], "H": out["H"],

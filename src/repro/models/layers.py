@@ -404,8 +404,9 @@ def apply_attention(p, cfg: ArchConfig, x: jax.Array, *,
             # bounds and are dropped.
             lens = jnp.broadcast_to(jnp.reshape(cache_len, (-1,)), (B,))
             if block_table is not None:
-                kc = paged_scatter(kc, block_table, lens, k)
-                vc = paged_scatter(vc, block_table, lens, v)
+                with jax.named_scope("kv_write"):
+                    kc = paged_scatter(kc, block_table, lens, k)
+                    vc = paged_scatter(vc, block_table, lens, v)
                 if cfg.decode_attn == "kernel" and S == 1:
                     # block-sparse Pallas kernel: reads only mapped,
                     # in-depth blocks from the pool — HBM traffic
@@ -427,8 +428,9 @@ def apply_attention(p, cfg: ArchConfig, x: jax.Array, *,
             else:
                 rows = jnp.arange(B)[:, None]
                 idx = lens[:, None] + jnp.arange(S)[None, :]
-                kc = kc.at[rows, idx].set(k, mode="drop")
-                vc = vc.at[rows, idx].set(v, mode="drop")
+                with jax.named_scope("kv_write"):
+                    kc = kc.at[rows, idx].set(k, mode="drop")
+                    vc = vc.at[rows, idx].set(v, mode="drop")
                 out = decode_attention(q, kc, vc, lens + S)
             new_kv = (kc, vc)
         else:

@@ -276,7 +276,14 @@ def prefill_chunk(params, cfg: ArchConfig, tokens: jax.Array, cache: dict,
     mid-prefill slot's len).  Hidden outputs are discarded: the engine
     re-feeds the prompt's last token at activation, same as batch
     prefill.  Bit-exact vs ``prefill`` on the same bucketed prompt
-    (tests/test_chunked_prefill.py)."""
+    (tests/test_chunked_prefill.py).  Named scope ``prefill_chunk``."""
+    with jax.named_scope("prefill_chunk"):
+        return _prefill_chunk(params, cfg, tokens, cache, slot, offset,
+                              new_len, span)
+
+
+def _prefill_chunk(params, cfg, tokens, cache, slot, offset, new_len,
+                   span):
     row = jax.lax.dynamic_slice_in_dim(cache["block_table"], slot, 1, 0)
     x = L.apply_embed(params["embed"], tokens)
 
@@ -303,12 +310,14 @@ def _decode_block(bp, cfg, x, kv, cache_len, block_table=None):
     cache_len () or (B,): per-slot depths give per-slot RoPE positions.
     """
     pos = jnp.reshape(cache_len, (-1, 1))
-    h, new_kv = L.apply_attention(
-        bp["attn"], cfg, L.rms_norm(x, bp["ln1"]), positions=pos,
-        kv_cache=(kv["k"], kv["v"]), cache_len=cache_len,
-        block_table=block_table)
+    with jax.named_scope("attention"):
+        h, new_kv = L.apply_attention(
+            bp["attn"], cfg, L.rms_norm(x, bp["ln1"]), positions=pos,
+            kv_cache=(kv["k"], kv["v"]), cache_len=cache_len,
+            block_table=block_table)
     x = x + h
-    x = x + L.apply_mlp(bp["mlp"], cfg, L.rms_norm(x, bp["ln2"]))
+    with jax.named_scope("mlp"):
+        x = x + L.apply_mlp(bp["mlp"], cfg, L.rms_norm(x, bp["ln2"]))
     return x, {"k": new_kv[0], "v": new_kv[1]}
 
 
@@ -319,7 +328,14 @@ def decode_hidden(params, cfg: ArchConfig, token: jax.Array, cache: dict):
     slot's PRE-step depth and returns ``(hidden, new_cache)`` with
     ``len`` advanced by one; the uncertain head over ``hidden`` is the
     shared ``uncertain_head.head_outputs`` (fed the pre-step depths).
+    Named scope ``decode_body`` (blocks: ``attention``, its
+    ``kv_write``, ``mlp``).
     """
+    with jax.named_scope("decode_body"):
+        return _decode_hidden(params, cfg, token, cache)
+
+
+def _decode_hidden(params, cfg: ArchConfig, token: jax.Array, cache: dict):
     x = L.apply_embed(params["embed"], token[:, None])
     x = constrain(x, "batch", None, None)
     cache_len = cache["len"]

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
@@ -47,8 +48,15 @@ def head_outputs(params, cfg: ArchConfig, hidden, cache_len, key,
     ``len`` by the time the head runs).  ``num_samples`` overrides
     ``cfg.mc_samples`` for the cheap draft head (0 = mean head, greedy
     argmax of the softmax-mean, no draws at all).  Returns
-    {next_token, H, SE, MI, p_max} per slot.
+    {next_token, H, SE, MI, p_max} per slot.  Named scope
+    ``bayes_head`` (its ``sigma``; the kernel's ``vocab_pad``).
     """
+    with jax.named_scope("bayes_head"):
+        return _head_outputs(params, cfg, hidden, cache_len, key,
+                             num_samples)
+
+
+def _head_outputs(params, cfg, hidden, cache_len, key, num_samples):
     head = params["head"]
     S = cfg.mc_samples if num_samples is None else num_samples
     transformer = cfg.family in ("dense", "vlm")
@@ -60,8 +68,10 @@ def head_outputs(params, cfg: ArchConfig, hidden, cache_len, key,
         # oracle runs.  Softcapped heads keep the explicit-logits path.
         from repro.kernels import ops, rng
         q = head["q"]
+        with jax.named_scope("sigma"):
+            sigma = q.sigma
         unc = ops.uncertainty_head_sampled(
-            hidden, q.mu, q.sigma, rng.seed_from_key(key), num_samples=S)
+            hidden, q.mu, sigma, rng.seed_from_key(key), num_samples=S)
         return {
             "next_token": unc["pred"],
             "H": unc["H"], "SE": unc["SE"], "MI": unc["MI"],
