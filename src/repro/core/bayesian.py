@@ -73,11 +73,30 @@ class GaussianVariational:
         kl = 0.5 * (s2 / p2 + self.mu ** 2 / p2 - 1.0 - jnp.log(s2 / p2))
         return kl.sum()
 
+    def served(self) -> "GaussianServed":
+        """The posterior in the form a serving head reads: sigma made
+        once, not recomputed from rho on every read."""
+        return GaussianServed(mu=self.mu, sigma=softplus(self.rho))
+
+
+@dataclasses.dataclass(frozen=True)
+class GaussianServed:
+    """q(w) = N(mu, sigma^2) with sigma materialized: the inference-only
+    form of a ``GaussianVariational`` (same ``.mu`` / ``.sigma``), made
+    once by the serving runner.  Training keeps ``rho``."""
+    mu: jax.Array
+    sigma: jax.Array
+
 
 jax.tree_util.register_pytree_node(
     GaussianVariational,
     lambda g: ((g.mu, g.rho), None),
     lambda _, c: GaussianVariational(*c),
+)
+jax.tree_util.register_pytree_node(
+    GaussianServed,
+    lambda g: ((g.mu, g.sigma), None),
+    lambda _, c: GaussianServed(*c),
 )
 
 

@@ -34,7 +34,10 @@ With an explicit xi operand the same structure runs in interpret mode as
 the validation path (both passes read the same xi tile).
 
 Vocab padding is handled by masking inside the kernel (static closure over
-the true V), so any vocabulary size works with 128-aligned tiles.
+the true V), so any vocabulary size works with 128-aligned tiles.  The fused
+kernel pads nothing: its grid runs over ``cdiv(V, bv)`` tiles and the last
+one is ragged, its columns past V read as whatever lies there and masked
+before any reduction (no per-call copy of mu and sigma).
 """
 
 from __future__ import annotations
@@ -301,20 +304,16 @@ def uncertainty_head_fused_kernel(x: jax.Array, mu: jax.Array,
     xi=None selects the in-kernel PRNG fast path (TPU only); an explicit
     xi (S, M, V) selects the validation path (runs in interpret mode).
     Pass 2 regenerates the logits tiles (two small matmuls + the replayed
-    variates) instead of re-reading an (S, M, V) HBM buffer.
+    variates) instead of re-reading an (S, M, V) HBM buffer.  V need not
+    divide into ``bv``: the last vocab tile is ragged and masked, so mu
+    and sigma are read where they lie, never padded.
     """
     m, k = x.shape
     _, v = mu.shape
     s = num_samples
     bm = min(bm, m)
     assert m % bm == 0, (m, bm)
-    v_pad = (-v) % bv
-    if v_pad:
-        with jax.named_scope("vocab_pad"):
-            mu = jnp.pad(mu, ((0, 0), (0, v_pad)))
-            sigma = jnp.pad(sigma, ((0, 0), (0, v_pad)))
-    vp = v + v_pad
-    grid = (m // bm, vp // bv)
+    grid = (m // bm, pl.cdiv(v, bv))
     in_kernel_rng = xi is None
     seed_arr = jnp.asarray(seed, jnp.int32).reshape(1, 1)
 
@@ -327,8 +326,6 @@ def uncertainty_head_fused_kernel(x: jax.Array, mu: jax.Array,
     operands = [seed_arr, x, mu, sigma]
     if not in_kernel_rng:
         assert xi.shape == (s, m, v), (xi.shape, (s, m, v))
-        if v_pad:
-            xi = jnp.pad(xi, ((0, 0), (0, 0), (0, v_pad)))
         in_specs.append(pl.BlockSpec((s, bm, bv), lambda i, j: (0, i, j)))
         operands.append(xi)
 

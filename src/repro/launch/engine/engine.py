@@ -254,8 +254,10 @@ class ServeEngine:
         self.params = self.runner.params
         # escalation sidecars: unplaced params + head-draw knobs so
         # escalation_runner can build a second ModelRunner (its own jit
-        # cache) per distinct verify S, on demand
-        self._base_params = params
+        # cache) per distinct verify S, on demand; off-mesh they are the
+        # runner's, whose head sigma is already made
+        self._base_params = params if mesh is not None \
+            else self.runner.params
         self._entropy = entropy
         self._mi_threshold = mi_threshold
         self._se_threshold = se_threshold

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.bayesian import GaussianServed, GaussianVariational
 from repro.core.entropy import KernelEntropy
 from repro.launch import mesh as meshlib
 from repro.launch import steps as S
@@ -52,6 +53,31 @@ _KV_HEAD_LEAVES = ("k", "v", "attn_k", "attn_v", "ck", "cv")
 # unsharded program elides, so a mesh runner would drift from the
 # unsharded one.
 _COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
+
+def served_params(params):
+    """``params`` with every ``GaussianVariational`` in its served form.
+
+    All the sigmas are made in one program (scope
+    ``bayes_head/prepare``), once, so no decode step recomputes
+    softplus(rho).  mu and every other leaf are the caller's arrays,
+    neither copied nor donated; a tree with nothing to prepare comes
+    back as it is.
+    """
+    is_q = lambda x: isinstance(x, GaussianVariational)  # noqa: E731
+    leaves, tree = jax.tree.flatten(params, is_leaf=is_q)
+    qs = [x for x in leaves if is_q(x)]
+    if not qs:
+        return params
+
+    def sigmas(qs):
+        with jax.named_scope("bayes_head/prepare"):
+            return [q.served().sigma for q in qs]
+
+    made = iter(jax.jit(sigmas)(qs))
+    return jax.tree.unflatten(tree, [
+        GaussianServed(mu=x.mu, sigma=next(made)) if is_q(x) else x
+        for x in leaves])
 
 
 def resolve_mesh(spec: Optional[str]) -> Optional[Mesh]:
@@ -83,7 +109,9 @@ class ModelRunner:
     KV leaves are sharded on their head axis (replicating when the
     head count doesn't divide the model axis), and every callable runs
     under the serve-mesh context so the layer-level all-gather
-    constraints bake in at trace time.
+    constraints bake in at trace time.  The Bayesian head is served in
+    the form its kernel reads (``served_params``), made before any
+    placement.
     """
 
     def __init__(self, params, cfg, *, max_len: int, chunk: int,
@@ -100,6 +128,7 @@ class ModelRunner:
         self.kv_block = kv_block
         self.kv_blocks = kv_blocks
         self.mesh = mesh
+        params = served_params(params)
         self.params = params if mesh is None else jax.device_put(
             params, serve_shardings_for(params, mesh))
         paged = kv_layout == "paged"

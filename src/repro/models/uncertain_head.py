@@ -49,7 +49,9 @@ def head_outputs(params, cfg: ArchConfig, hidden, cache_len, key,
     ``cfg.mc_samples`` for the cheap draft head (0 = mean head, greedy
     argmax of the softmax-mean, no draws at all).  Returns
     {next_token, H, SE, MI, p_max} per slot.  Named scope
-    ``bayes_head`` (its ``sigma``; the kernel's ``vocab_pad``).
+    ``bayes_head``.  The head reads only ``.mu`` and ``.sigma``: the
+    serving runner hands it the served form, whose sigma was made once
+    (``runner.served_params``); a training tree's sigma is softplus(rho).
     """
     with jax.named_scope("bayes_head"):
         return _head_outputs(params, cfg, hidden, cache_len, key,
@@ -68,10 +70,8 @@ def _head_outputs(params, cfg, hidden, cache_len, key, num_samples):
         # oracle runs.  Softcapped heads keep the explicit-logits path.
         from repro.kernels import ops, rng
         q = head["q"]
-        with jax.named_scope("sigma"):
-            sigma = q.sigma
         unc = ops.uncertainty_head_sampled(
-            hidden, q.mu, sigma, rng.seed_from_key(key), num_samples=S)
+            hidden, q.mu, q.sigma, rng.seed_from_key(key), num_samples=S)
         return {
             "next_token": unc["pred"],
             "H": unc["H"], "SE": unc["SE"], "MI": unc["MI"],

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.bayesian import GaussianVariational
+from repro.core.bayesian import GaussianServed, GaussianVariational
 
 # ---------------------------------------------------------------------------
 # activation-constraint context (set by launch scripts, no-op otherwise)
@@ -193,13 +193,13 @@ def serve_pspecs(params: Any) -> Any:
     """
 
     def spec_leaf(path, leaf):
-        if isinstance(leaf, GaussianVariational):
+        if isinstance(leaf, (GaussianVariational, GaussianServed)):
             s = _serve_spec_for(path + "/mu", leaf.mu.ndim)
-            return GaussianVariational(mu=s, rho=s)  # type: ignore
+            return type(leaf)(s, s)  # type: ignore
         return _serve_spec_for(path, getattr(leaf, "ndim", 0))
 
     def walk(path, node):
-        if isinstance(node, GaussianVariational):
+        if isinstance(node, (GaussianVariational, GaussianServed)):
             return spec_leaf(path, node)
         if isinstance(node, dict):
             return {k: walk(f"{path}/{k}", v) for k, v in node.items()}

@@ -234,6 +234,26 @@ class TestSVI:
         # ...but the sigma gradient still flows (STE)
         assert abs(float(g.rho[0])) > 0
 
+    def test_served_sigma_is_softplus_rho_bitwise(self):
+        """The serving runner's prepared head: sigma is softplus(rho),
+        bit for bit, made in one program; mu and the other leaves are
+        the caller's arrays, and a served tree passes through."""
+        from repro.core.bayesian import GaussianServed, softplus
+        from repro.launch.engine.runner import served_params
+        q = GaussianVariational(
+            mu=jax.random.normal(jax.random.key(1), (16, 40)),
+            rho=4.0 * jax.random.normal(jax.random.key(2), (16, 40)))
+        want = np.asarray(softplus(q.rho))
+        assert isinstance(q.served(), GaussianServed)
+        np.testing.assert_array_equal(np.asarray(q.served().sigma), want)
+        w = jnp.ones((3,))
+        tree = served_params({"head": {"q": q}, "w": w})
+        got = tree["head"]["q"]
+        assert isinstance(got, GaussianServed)
+        assert got.mu is q.mu and tree["w"] is w
+        np.testing.assert_array_equal(np.asarray(got.sigma), want)
+        assert served_params(tree) is tree
+
     def test_mc_forward_shapes(self):
         out = mc_forward(lambda k: jax.random.normal(k, (3,)),
                          jax.random.key(0), 10)

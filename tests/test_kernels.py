@@ -278,6 +278,24 @@ def test_fused_head_matches_ref_with_explicit_entropy():
     np.testing.assert_array_equal(got["pred"], want["pred"])
 
 
+def test_sampled_head_ragged_vocab_tile_matches_ref():
+    """V=1000 over 512-wide vocab tiles: the kernel's last tile is
+    ragged (nothing padded) and its columns past V are masked, so the
+    seeded head matches the seeded oracle on the same variates."""
+    m, k, v, s = 8, 64, 1000, 10
+    ks = jax.random.split(jax.random.key(38), 3)
+    x = _rand(ks[0], m, k)
+    mu = _rand(ks[1], k, v, scale=0.2)
+    sg = jnp.abs(_rand(ks[2], k, v, scale=0.05))
+    got = ops.uncertainty_head_sampled(x, mu, sg, 7, num_samples=s,
+                                       impl="pallas", bm=8, bv=512)
+    want = ref.uncertainty_head_sampled(x, mu, sg, 7, s)
+    for name in ("H", "SE", "MI", "p_max"):
+        np.testing.assert_allclose(got[name], want[name], rtol=1e-4,
+                                   atol=1e-5, err_msg=name)
+    np.testing.assert_array_equal(got["pred"], want["pred"])
+
+
 def test_sampled_head_moments_and_determinism():
     m, k, v, s = 8, 16, 12, 10
     ks = jax.random.split(jax.random.key(36), 3)

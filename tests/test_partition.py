@@ -91,6 +91,25 @@ def test_sanitized_specs_divide_for(arch, mesh, rules):
         assert sharded > 0, arch
 
 
+def test_serve_pspecs_place_served_head_by_mu():
+    """The served head (sigma made once from rho) is placed as the
+    training form is: mu and sigma both take mu's spec, so mesh serving
+    reads the same columns it did."""
+    from repro.core.bayesian import GaussianServed
+    from repro.launch.engine.runner import served_params
+    cfg = get_config("qwen2_1_5b")
+    shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.key(0))
+    served = jax.eval_shape(served_params, shapes)
+    want = serve_pspecs(shapes)["head"]["q"].mu
+    assert want == P(None, "model")
+    got = serve_pspecs(served)["head"]["q"]
+    assert isinstance(got, GaussianServed)
+    assert got.mu == want and got.sigma == want
+    clean = sanitize_pspecs(serve_pspecs(served), served, MESH_1x4)
+    assert clean["head"]["q"].sigma == P(None, "model")
+
+
 # ---------------------------------------------------------------------------
 # shardings_for round-trip on a real 1x2 debug mesh (subprocess: the
 # forced device count must be pinned before jax initializes)

@@ -260,6 +260,35 @@ class TestEngine:
         assert not np.allclose(a, c)
 
 
+    @pytest.mark.parametrize("entropy", ["kernel", "operand"])
+    def test_served_head_decodes_bitwise_as_rho(self, setup, monkeypatch,
+                                                entropy):
+        """The runner's served head (sigma made once) decodes the same
+        tokens and (H, SE, MI) bit for bit as the head that recomputes
+        softplus(rho) inside every step."""
+        from repro.launch.engine import runner as runner_mod
+        cfg, params, prompts = setup
+        cfg = dataclasses.replace(cfg, head_entropy=entropy)
+
+        def run():
+            engine = ServeEngine(
+                params, cfg, num_slots=2, max_len=32, chunk=4,
+                entropy=KernelEntropy(seed=5) if entropy == "kernel"
+                else None)
+            res = engine.run([_req(i, prompts[i], 6) for i in range(3)])
+            return engine, {k: np.asarray([getattr(r, k) for r in
+                                           res["requests"]], np.float32)
+                            for k in ("tokens", "H", "SE", "MI")}
+
+        served_engine, served = run()
+        assert "rho" not in vars(served_engine.params["head"]["q"])
+        monkeypatch.setattr(runner_mod, "served_params", lambda p: p)
+        rho_engine, from_rho = run()
+        assert rho_engine.params["head"]["q"] is params["head"]["q"]
+        for k in served:
+            np.testing.assert_array_equal(served[k], from_rho[k], err_msg=k)
+
+
 # ---------------------------------------------------------------------------
 # train-step seeding (satellite bugfix)
 # ---------------------------------------------------------------------------

@@ -253,6 +253,11 @@ def test_scan_decode_hlo_carries_the_named_scopes(dense):
                           jnp.zeros((2,), bool), flags).as_text(
         dialect="hlo", debug_info=True)
     op_names = set(re.findall(r'op_name="([^"]+)"', hlo))
-    for scope in ("decode_step/decode_body", "attention/kv_write", "mlp/",
-                  "decode_step/bayes_head/sigma"):
+    for scope in ("decode_step/decode_body", "attention/kv_write", "mlp/"):
         assert any(scope in n for n in op_names), scope
+    # the head's sigma is made once by the runner and its operands are
+    # never padded: no step recomputes softplus(rho) or copies mu/sigma
+    for gone in ("bayes_head/sigma", "vocab_pad"):
+        assert not any(gone in n for n in op_names), gone
+    assert not any("bayes_head" in n and n.endswith("/log1p")
+                   for n in op_names)

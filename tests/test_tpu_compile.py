@@ -54,19 +54,24 @@ def _compiled_text(one_chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def test_uncertainty_head_in_kernel_rng(one_chip):
+@pytest.mark.parametrize("head_dtype", [jnp.bfloat16, jnp.float32])
+def test_uncertainty_head_in_kernel_rng(one_chip, head_dtype):
     """The served decode head: M=8 rows (a 4-slot batch padded to the
-    sublane), the full vocab, S=10 draws generated in-kernel."""
+    sublane), the full vocab, S=10 draws generated in-kernel.  V=151,936
+    is not a multiple of the 512-wide vocab tile: Mosaic takes the
+    ragged last tile, and mu and sigma (float32 as served) are read in
+    place, with no pad in the program."""
     m, k, v = 8, CFG.d_model, CFG.vocab_size
     bm, bv = head_tiles(m)
-    assert bm == 8
+    assert bm == 8 and v == 151_936 and v % bv
     hlo = _compiled_text(
         one_chip,
         lambda x, mu, sg, seed: uncertainty_head_fused_kernel(
             x, mu, sg, seed, num_samples=CFG.mc_samples, bm=bm, bv=bv),
-        ((m, k), jnp.bfloat16), ((k, v), jnp.bfloat16),
-        ((k, v), jnp.bfloat16), ((), jnp.int32))
+        ((m, k), jnp.bfloat16), ((k, v), head_dtype),
+        ((k, v), head_dtype), ((), jnp.int32))
     assert "tpu_custom_call" in hlo
+    assert " pad(" not in hlo
 
 
 def test_paged_decode_attention(one_chip):
