@@ -2,8 +2,10 @@
 
 After the window has closed and the program's state is freed, a sample
 of the finished requests (drawn from the seed, the longest always in
-it) is run through the configuration's plain reference, one request at
-a time over its whole sequence: the prompt, the prompt's last token
+it) is run through the configuration's plain reference
+(``bench/references/<reference>.py``, handed the configuration file's
+dict, from which it reads its own sizes), one request at a time over
+its whole sequence: the prompt, the prompt's last token
 again (the engine feeds it as the first decode input), then every
 served token but the last.  At each served position the reference gives
 the float32 mean logits, and the head's estimators averaged over
@@ -79,18 +81,12 @@ def sample(requests: list, seed: int, k: int) -> list:
     return [requests[longest]] + [requests[rest[i]] for i in sorted(pick)]
 
 
-def _shape(config: dict) -> tuple:
-    return (config["num_attention_heads"], config["num_key_value_heads"],
-            config["head_dim"], float(config["rope_theta"]),
-            float(config["rms_norm_eps"]))
-
-
 def _hidden(ref, w, config, seq, precision):
     import jax.numpy as jnp
     T = len(seq)
     pad = np.zeros((-(-T // SEQ_BUCKET) * SEQ_BUCKET,), np.int32)
     pad[:T] = seq
-    return ref.hidden_states(w, jnp.asarray(pad), _shape(config), precision)
+    return ref.hidden_states(w, jnp.asarray(pad), config, precision)
 
 
 def _head(ref, w, x, key, samples, sets, precision):

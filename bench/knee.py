@@ -58,15 +58,14 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     import jax
     import numpy as np
-    from bench import run, serve, spec, traffic, weights
+    from bench import run, serve, spec, traffic
     if jax.devices()[0].platform != "tpu":
         print("knee: no TPU", file=sys.stderr)
         return 3
     run.use_compile_cache()
     cell = spec.load_cell(args.workload)
     config, vocab = cell.config, cell.config["vocab_size"]
-    cfg = serve.arch_config(config)
-    params = serve.program_params(weights.generate(config, args.seed), cfg)
+    cfg, params = serve.program(config, args.seed)
     engine = serve.build_engine(config, cell.traffic, params, cfg, args.seed)
     engine.run(serve.warmup_requests(config, cell.traffic, vocab, args.seed))
     for rate in (float(r) for r in args.rates.split(",")):

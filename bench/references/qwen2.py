@@ -13,7 +13,14 @@ argmax.
 
 Everything runs in float32 at ``highest`` matmul precision, one request
 at a time over its whole sequence, with no cache, kernel or batching.
-It imports nothing of the program: weights come from ``bench.weights``.
+It imports nothing of the program: weights come from ``bench.weights``,
+under the names ``bench/families/qwen2.py`` gives them.
+
+A reference gives ``bench.check`` three calls:
+``hidden_states(w, tokens, config, precision)`` (``config`` is the
+configuration file's dict, from which it reads its own sizes),
+``head_stats(w, x, key, samples, sets, precision)`` and
+``logits_at(w, x, tokens)``.
 
 ``precision="control"`` is the control: each matmul operand is rounded
 to the precision next below the one the configuration states, with
@@ -64,11 +71,18 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def hidden_states(w: dict, tokens, shape: tuple, precision: str):
-    """Final-norm hidden states (T, d) of one sequence ``tokens`` (T,).
+def hidden_states(w: dict, tokens, config: dict, precision: str):
+    """Final-norm hidden states (T, d) of one sequence ``tokens`` (T,)
+    under the configuration file's dict ``config``."""
+    return _hidden_states(w, tokens, (
+        config["num_attention_heads"], config["num_key_value_heads"],
+        config["head_dim"], float(config["rope_theta"]),
+        float(config["rms_norm_eps"])), precision)
 
-    ``shape`` = (H, Hkv, hd, rope_theta, rms_norm_eps)."""
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _hidden_states(w: dict, tokens, shape: tuple, precision: str):
+    """``shape`` = (H, Hkv, hd, rope_theta, rms_norm_eps)."""
     H, Hkv, hd, theta, eps = shape
     T = tokens.shape[0]
     x = w["embed"][tokens].astype(jnp.float32)

@@ -120,11 +120,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     vocab = config["vocab_size"]
     used = devices[:cell.chips]
 
-    cfg = serve.arch_config(config)
-    w = weights.generate(config, seed)
-    params = serve.program_params(w, cfg)
+    cfg, params = serve.program(config, seed, cell.root)
     jax.block_until_ready(params)
-    del w
     engine = serve.build_engine(config, mix, params, cfg, seed)
     t0 = time.perf_counter()
     engine.run(serve.warmup_requests(config, mix, vocab, seed))
@@ -215,7 +212,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
                "peak": spec.peak(used[0].device_kind)}
         metrics = {}
         for m in cell.per_layer:
-            v = spec.metric_reader(m["name"])(ctx)
+            v = spec.metric_reader(m["name"], cell.root)(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
@@ -237,8 +234,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     gc.unfreeze()
     gc.collect()
     t0 = time.perf_counter()
-    ref = spec.reference_module(config["reference"])
-    w = weights.generate(config, seed)
+    ref = spec.reference_module(config["reference"], cell.root)
+    w = weights.generate(config, seed, cell.root)
     per_pos = check.readings(ref, w, config, served, seed)
     nums = check.numbers(per_pos)
     ok, lines = check.judge(nums, cell.limits)

@@ -3,19 +3,21 @@
 The engine is the program's ``ServeEngine`` built as
 ``repro.launch.serve.build_engine`` builds it, from the configuration
 file's widths and engine settings, with the weights of
-``bench.weights``.  ``ServeEngine.run`` is a blocking call over a list
-of requests whose arrivals are counted in decode steps; the harness
-gives each request a token list that stamps the host clock as the
-engine appends to it, and reads every latency from those stamps.
+``bench.weights`` in the tree of the configuration's family.
+``ServeEngine.run`` is a blocking call over a list of requests whose
+arrivals are counted in decode steps; the harness gives each request a
+token list that stamps the host clock as the engine appends to it, and
+reads every latency from those stamps.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import time
+from pathlib import Path
 
 import numpy as np
 
+from bench import spec, weights
 from bench import traffic as T
 
 
@@ -139,44 +141,18 @@ class StampedTokens(list):
         self.times.clear()
 
 
-def arch_config(config: dict):
-    """The program's ArchConfig for a configuration file: the registry
-    entry it names gives the family, every size comes from the file."""
-    from repro.configs.registry import get_config
-    head = config["bayesian_head"]
-    return dataclasses.replace(
-        get_config(config["registry"]),
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        param_dtype=config["torch_dtype"],
-        mlp_activation=config["hidden_act"],
-        norm_eps=float(config["rms_norm_eps"]),
-        rope_theta=float(config["rope_theta"]), qkv_bias=True,
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        bayesian_head=True, mc_samples=int(head["mc_samples"]),
-        head_init_sigma=float(head["init_sigma"]),
-        head_entropy=head["entropy"])
-
-
-def program_params(w: dict, cfg):
-    """``bench.weights`` in the program's parameter tree, checked against
-    the shapes and types of the program's own ``init_params``."""
+def program(config: dict, seed: int, root: Path = spec.ROOT):
+    """(ArchConfig, parameters) of a configuration for ``seed``: its
+    family (``bench/families/<reference>.py``) maps the configuration
+    and ``bench.weights`` into the program's types, and the tree is
+    checked against the shapes and types of the program's own
+    ``init_params``."""
     import jax
-    from repro.core.bayesian import GaussianVariational
     from repro.models import registry as M
-    blocks = {"ln1": w["ln1"], "ln2": w["ln2"],
-              "attn": {n: w[n] for n in ("wq", "bq", "wk", "bk", "wv",
-                                         "bv", "wo")},
-              "mlp": {"w1": w["w_gate"], "w3": w["w_up"],
-                      "w2": w["w_down"]}}
-    params = {"embed": {"table": w["embed"]}, "blocks": blocks,
-              "final_norm": w["final_norm"],
-              "head": {"q": GaussianVariational(mu=w["head_mu"],
-                                                rho=w["head_rho"])}}
+    family = spec.family_module(config["reference"], root)
+    cfg = family.arch_config(config)
+    params = family.program_params(weights.generate(config, seed, root),
+                                   cfg)
     want = jax.eval_shape(lambda k: M.init_params(k, cfg),
                           jax.random.key(0))
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
@@ -186,7 +162,7 @@ def program_params(w: dict, cfg):
             zip(jax.tree.leaves(want), jax.tree.leaves(got))):
         raise ValueError("bench weights do not match the program's "
                          "parameter tree")
-    return params
+    return cfg, params
 
 
 def build_engine(config: dict, mix: dict, params, cfg, seed: int):

@@ -1,12 +1,24 @@
 """Finds a cell's files by the names ``BENCHMARK.json`` gives them.
 
-Nothing here knows a particular configuration, mix or metric: a later
-change adds a cell by adding files and entries, never by editing code.
+Nothing here knows a particular configuration, architecture, mix or
+metric: a later change adds a cell by adding files and entries, never by
+editing code.  A configuration of a new architecture brings:
+
+* ``bench/configs/<config>.json``, whose ``"reference"`` names the
+  architecture;
+* ``bench/families/<reference>.py``: the program's ``ArchConfig``, the
+  per-layer weights, the parameter tree and the body's FLOPs per token;
+* ``bench/references/<reference>.py``: the plain forward that decides
+  ``correct``;
+* ``bench/traffic/<mix>.json`` unless it serves an existing mix,
+  ``bench/limits/<cell>.json``, and optionally
+  ``bench/metrics/<metric>.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -27,6 +39,7 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    root: Path = ROOT
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -55,7 +68,8 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
                 traffic=traffic,
                 limits=limits,
                 end_to_end=_cell_metrics(bench["end_to_end"], name),
-                per_layer=_cell_metrics(bench["per_layer"], name))
+                per_layer=_cell_metrics(bench["per_layer"], name),
+                root=root)
 
 
 def _load_module(path: Path, name: str) -> ModuleType:
@@ -76,6 +90,16 @@ def reference_module(name: str, root: Path = ROOT) -> ModuleType:
     """``bench/references/<name>.py``: a configuration's plain forward."""
     return _load_module(root / "bench" / "references" / f"{name}.py",
                         f"bench_reference_{name}")
+
+
+@functools.cache
+def family_module(name: str, root: Path = ROOT) -> ModuleType:
+    """``bench/families/<name>.py``: what the harness needs of a
+    configuration's architecture, for the name its ``"reference"`` key
+    gives.  Loaded once per process: the work counts call it for every
+    served token."""
+    return _load_module(root / "bench" / "families" / f"{name}.py",
+                        f"bench_family_{name}")
 
 
 def peak(device_kind: str, root: Path = ROOT) -> dict:

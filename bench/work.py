@@ -9,6 +9,10 @@ the configuration serves them.
 
 from __future__ import annotations
 
+from pathlib import Path
+
+from bench import spec
+
 BF16 = 2
 F32 = 4
 
@@ -17,7 +21,7 @@ def shape(config: dict) -> dict:
     return {"L": config["num_hidden_layers"], "d": config["hidden_size"],
             "H": config["num_attention_heads"],
             "Hkv": config["num_key_value_heads"], "hd": config["head_dim"],
-            "ff": config["intermediate_size"], "V": config["vocab_size"],
+            "V": config["vocab_size"],
             "S": config["bayesian_head"]["mc_samples"]}
 
 
@@ -41,13 +45,12 @@ def decode_attention(config: dict, depth: int) -> tuple[float, float]:
     return flops, byts
 
 
-def body_flops_per_token(config: dict) -> float:
-    """FLOPs of one token through every block's projections and MLP
-    (attention over the cache is ``decode_attention``)."""
-    s = shape(config)
-    d, hq, hkv = s["d"], s["H"] * s["hd"], s["Hkv"] * s["hd"]
-    params = d * hq + 2 * d * hkv + hq * d + 3 * d * s["ff"]
-    return 2.0 * params * s["L"]
+def body_flops_per_token(config: dict, root: Path = spec.ROOT) -> float:
+    """FLOPs of one token through every block, as the configuration's
+    family (``bench/families/<reference>.py``) counts them (attention
+    over the cache is ``decode_attention``)."""
+    return spec.family_module(config["reference"],
+                              root).body_flops_per_token(config)
 
 
 def decode_token_flops(config: dict, depth: int) -> float:

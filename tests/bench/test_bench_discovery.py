@@ -1,12 +1,16 @@
 """A configuration, a mix and a metric added as new files are found by
-name: BENCHMARK.json gains entries, and no other file changes."""
+name: BENCHMARK.json gains entries, and no other file changes.  So is a
+configuration of another architecture, with its family and reference."""
 
 import hashlib
 import json
 import shutil
 
+import jax
+import pytest
+
 from _paths import DATA, ROOT
-from bench import spec, traffic
+from bench import run, serve, spec, traffic
 
 
 def _digests(root):
@@ -14,11 +18,27 @@ def _digests(root):
             for p in sorted((root / "bench").rglob("*")) if p.is_file()}
 
 
-def test_new_files_are_found_without_editing_existing_ones(tmp_path):
-    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+def _copy_bench(root):
+    """The benchmark's files under ``root``, and their digests."""
+    shutil.copytree(ROOT / "bench", root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
-    before = _digests(tmp_path)
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    return _digests(root)
+
+
+def _add_architecture(root, name, family, reference):
+    """A configuration of ``tiny.json``'s sizes whose ``"reference"`` is
+    ``name``, with that family and reference, as new files only."""
+    config = json.loads((DATA / "tiny.json").read_text())
+    config["reference"] = name
+    (root / f"bench/configs/{name}.json").write_text(json.dumps(config))
+    shutil.copy(family, root / f"bench/families/{name}.py")
+    shutil.copy(reference, root / f"bench/references/{name}.py")
+    return config
+
+
+def test_new_files_are_found_without_editing_existing_ones(tmp_path):
+    before = _copy_bench(tmp_path)
 
     # the new configuration, mix, limits and metric: files of their own
     shutil.copy(DATA / "tiny.json", tmp_path / "bench/configs/tiny.json")
@@ -58,12 +78,66 @@ def test_new_files_are_found_without_editing_existing_ones(tmp_path):
     assert {k: v for k, v in after.items() if k in before} == before
 
 
+def test_a_second_architecture_is_served_from_new_files(tmp_path):
+    """A bias-free GQA family, its reference, configuration, mix and
+    limits, added as files, run through ``run_cell`` and judged."""
+    before = _copy_bench(tmp_path)
+    nobias = DATA / "gqa_nobias"
+    config = _add_architecture(tmp_path, "gqa_nobias",
+                               nobias / "family.py", nobias / "reference.py")
+    shutil.copy(DATA / "tiny-chat.json",
+                tmp_path / "bench/traffic/tiny-chat.json")
+    shutil.copy(DATA / "tiny-limits.json",
+                tmp_path / "bench/limits/gqa-nobias.tiny-chat.json")
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "gqa-nobias", "source": "test",
+                             "file": "bench/configs/gqa_nobias.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "gqa-nobias.tiny-chat",
+                               "config": "gqa-nobias",
+                               "traffic": "tiny-chat", "chips": 1,
+                               "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    cfg, params = serve.program(config, 3, tmp_path)
+    assert not cfg.qkv_bias
+    assert set(params["blocks"]["attn"]) == {"wq", "wk", "wv", "wo"}
+    cell = spec.load_cell("gqa-nobias.tiny-chat", root=tmp_path)
+    out = run.run_cell(cell, 2**31 + 7, 2.0, False, jax.devices(),
+                       log=lambda *a: None)
+    assert out["correct"] is True, out["_lines"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+
+    after = _digests(tmp_path)
+    assert {k: v for k, v in after.items() if k in before} == before
+
+
+def test_a_family_with_a_wrong_tree_is_refused(tmp_path):
+    """The shared check against the program's ``init_params`` holds for
+    every family: Qwen2's biases left out of a tree whose ArchConfig
+    asks for them."""
+    _copy_bench(tmp_path)
+    family = tmp_path / "wrong_tree.py"
+    family.write_text(
+        (ROOT / "bench/families/qwen2.py").read_text()
+        + "\n\n_tree = program_params\n\n\n"
+        "def program_params(w, cfg):\n"
+        "    params = _tree(w, cfg)\n"
+        "    del params['blocks']['attn']['bq']\n"
+        "    return params\n")
+    config = _add_architecture(tmp_path, "wrong_tree", family,
+                               ROOT / "bench/references/qwen2.py")
+    with pytest.raises(ValueError, match="do not match"):
+        serve.program(config, 3, tmp_path)
+
+
 def test_every_named_file_exists():
     bench = spec.load_benchmark()
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"])
-        assert (ROOT / "bench/references"
-                / f"{cell.config['reference']}.py").is_file()
+        for kind in ("families", "references"):
+            assert (ROOT / "bench" / kind
+                    / f"{cell.config['reference']}.py").is_file()
     for m in bench["per_layer"]:
         assert callable(spec.metric_reader(m["name"]))
 

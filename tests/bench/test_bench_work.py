@@ -9,7 +9,8 @@ from bench import work
 
 QWEN2_1_5B = json.loads((ROOT / "bench/configs/qwen2-1.5b.json").read_text())
 # qwen2-7B's published widths at 14 of its 28 layers
-QWEN2_7B_L14 = {"num_hidden_layers": 14, "hidden_size": 3584,
+QWEN2_7B_L14 = {"reference": "qwen2", "num_hidden_layers": 14,
+                "hidden_size": 3584,
                 "num_attention_heads": 28, "num_key_value_heads": 4,
                 "head_dim": 128, "intermediate_size": 18944,
                 "vocab_size": 152064, "bayesian_head": {"mc_samples": 10}}
