@@ -33,7 +33,10 @@ class ArchConfig:
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0                # per-expert ff (deepseek fine-grained)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # read by no program path: the expert
+                                     # layer is dropless (models/moe.py);
+                                     # kept so that every config keeps its
+                                     # field set (tests/bench pins it)
     expert_sharding: str = "ep"      # "ep" (experts on model axis) | "tp"
 
     # --- SSM / hybrid ---
@@ -106,18 +109,11 @@ class ArchConfig:
     def param_count(self) -> int:
         """Analytic parameter count (embeddings + blocks + head)."""
         d, ff, v = self.d_model, self.d_ff, self.vocab_size
-        hd = self.head_dim
         emb = v * d * (1 if self.tie_embeddings else 2)
-        attn = d * (self.num_heads * hd) + 2 * d * (self.num_kv_heads * hd) \
-            + (self.num_heads * hd) * d
+        attn = self.attn_param_count
         dense_mlp = 3 * d * ff if self.mlp_activation in ("silu", "gelu") \
             else 2 * d * ff
-        if self.is_moe:
-            eff = self.moe_d_ff or ff
-            moe = self.num_experts * 3 * d * eff \
-                + self.num_shared_experts * 3 * d * eff + d * self.num_experts
-            block = attn + moe
-        elif self.family in ("ssm",):
+        if self.family in ("ssm",):
             din = self.ssm_expand * d
             h = din // self.ssm_head_dim
             block = d * (2 * din + 2 * self.ssm_state + h) \
@@ -140,13 +136,61 @@ class ArchConfig:
         return total
 
     @property
+    def attn_param_count(self) -> int:
+        """Parameters of one block's attention projections."""
+        d, hd = self.d_model, self.head_dim
+        return d * (self.num_heads * hd) + 2 * d * (self.num_kv_heads * hd) \
+            + (self.num_heads * hd) * d
+
+    @property
     def active_param_count(self) -> int:
-        """Per-token active params (MoE: top_k + shared experts only)."""
-        if not self.is_moe:
-            return self.param_count
+        """Per-token active params: all of them outside ``MoEConfig``."""
+        return self.param_count
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEConfig(ArchConfig):
+    """An ``ArchConfig`` of the ``moe`` family: what its expert layer
+    needs beyond the shared fields.  The defaults are the whole layer of
+    grok-1: no dense layer, renormalised top-k gates, every expert held.
+
+    A chip may hold a share of each layer's routed experts (an
+    expert-parallel deployment): ``experts_held`` of them from
+    ``expert_offset``.  The router still scores all ``num_experts``;
+    each token computes its held experts' part, and the shared experts
+    whole.  The parts of every share of a layer add up to the layer
+    with its shared experts counted once per share.
+    """
+
+    first_k_dense: int = 0           # leading layers with a dense d_ff MLP
+    norm_topk: bool = True           # renormalise the top-k gates to sum 1
+    experts_held: int = 0            # routed experts held here (0 -> all)
+    expert_offset: int = 0           # the first expert held here
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @property
+    def param_count(self) -> int:
+        """Embeddings, head and every block of the whole model (all
+        ``num_experts`` routed experts, whatever this chip holds)."""
+        d, eff = self.d_model, self.moe_d_ff or self.d_ff
+        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        attn = self.attn_param_count
+        experts = (self.num_experts + self.num_shared_experts) * 3 * d * eff
+        return emb + self.first_k_dense * (attn + 3 * d * self.d_ff) \
+            + self.moe_layers * (attn + experts + d * self.num_experts)
+
+    @property
+    def active_param_count(self) -> int:
         eff = self.moe_d_ff or self.d_ff
         inactive = (self.num_experts - self.top_k) * 3 * self.d_model * eff
-        return self.param_count - self.num_layers * inactive
+        return self.param_count - self.moe_layers * inactive
 
 
 @dataclasses.dataclass(frozen=True)

@@ -6,9 +6,9 @@ replicated over the axis, per-expert ff tensor-parallel); optimizer
 moments in bf16 to keep the 314B-param training state inside HBM
 (DESIGN.md §5).
 """
-from repro.configs.base import ArchConfig
+from repro.configs.base import MoEConfig
 
-CONFIG = ArchConfig(
+CONFIG = MoEConfig(
     name="grok-1-314b", family="moe",
     num_layers=64, d_model=6144, num_heads=48, num_kv_heads=8,
     head_dim=128, d_ff=32768, vocab_size=131072,

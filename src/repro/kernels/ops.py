@@ -302,19 +302,33 @@ def photonic_conv_sampled(x, mu, sigma, seed, impl: Impl = "auto",
     return y[:b]
 
 
-def head_tiles(m: int) -> tuple[int, int]:
-    """(bm, bv) row and vocab tiles of the fused head for ``m`` rows.
+# scoped VMEM the head's double-buffered (K, bv) float32 mu and sigma
+# blocks may take: what K=1536 at bv=512 takes (2 x 2 x 1536 x 512 x 4
+# bytes), the most that leaves room for the sample tiles in 16 MiB
+HEAD_WEIGHT_VMEM = 12 << 20
+
+
+def head_tiles(m: int, k: int) -> tuple[int, int]:
+    """(bm, bv) row and vocab tiles of the fused head for ``m`` rows of
+    width ``k``.
 
     One row tile covers a decode batch of up to 128 rows, rounded up to
     the 8-row sublane: its (3, S, bm) stats block then spans the whole
     padded row axis, which Mosaic's tiling rule admits at any multiple
-    of 8.  Larger batches tile rows by 128 (a lane multiple).  Above 64
-    rows the vocab tile halves to 256 to stay inside the 16 MiB of
-    scoped VMEM at K=1536, S=10: the (S, bm, bv) f32 sample tiles grow
-    with bm (bm=128 at bv=512 asks for 22.45 MiB on v5e).
+    of 8.  Larger batches tile rows by 128 (a lane multiple).  The
+    vocab tile is the widest of 512, 256, 128 whose double-buffered mu
+    and sigma blocks fit ``HEAD_WEIGHT_VMEM``: 512 at K=1536, 256 at
+    K=2048, 128 at K=3584.  Above 64 rows it halves (not below 128) to
+    stay inside the 16 MiB of scoped VMEM at S=10: the (S, bm, bv) f32
+    sample tiles grow with bm (bm=128 at K=1536, bv=512 asks for 22.45
+    MiB on v5e).  At K=1536 these are the tiles of every earlier
+    release.
     """
     bm = min(-(-m // 8) * 8, 128)
-    return bm, (512 if bm <= 64 else 256)
+    bv = 512
+    while bv > 128 and 2 * 2 * k * bv * 4 > HEAD_WEIGHT_VMEM:
+        bv //= 2
+    return bm, (bv if bm <= 64 else max(bv // 2, 128))
 
 
 @functools.partial(jax.jit, static_argnames=("num_samples", "impl", "bm",
@@ -326,13 +340,13 @@ def uncertainty_head_sampled(x, mu, sigma, seed, num_samples: int = 10,
 
     Pass 2 regenerates the sample logits from the replayed in-kernel
     stream instead of re-reading an (S, M, V) HBM buffer.  ``bm``/``bv``
-    default to ``head_tiles`` of the row count.
+    default to ``head_tiles`` of the row count and width.
     """
     use_pallas, interp = _resolve(impl)
     if not use_pallas:
         return ref.uncertainty_head_sampled(x, mu, sigma, seed, num_samples)
     m = x.shape[0]
-    auto_bm, auto_bv = head_tiles(m)
+    auto_bm, auto_bv = head_tiles(m, x.shape[1])
     bm, bv = bm or auto_bm, bv or auto_bv
     xp = _pad_to(x, 0, bm)
     xi = None

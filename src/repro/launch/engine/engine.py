@@ -311,17 +311,13 @@ class ServeEngine:
         """Open a chunked-prefill walk over ``req``'s prompt.
 
         The job carries the walk offset plus whatever state the family's
-        ``prefill_chunk`` threads between chunks: running expert load for
-        MoE capacity splits, SSM/conv recurrent state for hybrid, and the
-        encoder-frames-pending flag for encdec.
+        ``prefill_chunk`` threads between chunks: SSM/conv recurrent
+        state for hybrid, and the encoder-frames-pending flag for encdec.
         """
         job = {"req": req, "P": len(req.prompt), "span": span,
                "off": hit_len, "first": True}
         cfg = self.cfg
-        if cfg.family == "moe":
-            job["ex_off"] = jnp.zeros((cfg.num_layers, cfg.num_experts),
-                                      jnp.float32)
-        elif cfg.family == "hybrid":
+        if cfg.family == "hybrid":
             from repro.models.ssm import dims
             d_in, H, Pd, N = dims(cfg)
             job["state"] = {
@@ -332,9 +328,10 @@ class ServeEngine:
                                   cache["conv"].dtype)}
         return job
 
-    def _run_chunk(self, cache, slot: int, job: dict):
+    def _run_chunk(self, cache, slot: int, job: dict, stats):
         """Advance ``job`` by one prompt chunk; returns
-        ``(cache, done, shape_key)``.
+        ``(cache, done, shape_key)``.  The chunk's counters (moe), device
+        values, go to ``stats.counted`` unread.
 
         Padding-safe families pad every chunk to exactly prefill_chunk
         tokens (one compile per (chunk, span) pair; trailing junk either
@@ -356,9 +353,7 @@ class ServeEngine:
                 jnp.asarray(new_len, jnp.int32))
         fam = self.cfg.family
         variant = ""
-        if fam == "moe":
-            cache, job["ex_off"] = self._chunk_fn(*args, job["ex_off"], W)
-        elif fam == "hybrid":
+        if fam == "hybrid":
             cache, job["state"] = self._chunk_fn(*args, job["state"], W,
                                                  done)
             variant = "final" if done else ""
@@ -366,7 +361,9 @@ class ServeEngine:
             cache = self._chunk_first(*args, self._modality(1), W)
             variant = "first"
         else:
-            cache = self._chunk_fn(*args, W)
+            cache, counters = self._chunk_fn(*args, W)
+            if counters:
+                stats.counted("prefill", counters, 1)
         job["first"] = False
         job["off"] = new_len
         return cache, done, ("chunk", S_len, W, variant)
@@ -834,7 +831,7 @@ class ServeEngine:
                 with stats.span("serve.prefill_chunk") as sp:
                     req.prefill_started(sp.t0)
                     cache, done, shape_key = self._run_chunk(cache, slot,
-                                                             job)
+                                                             job, stats)
                     stats.prefill_chunks += 1
                 with stats.span("serve.prefill_wait") as wt:
                     jax.block_until_ready(cache)
@@ -933,6 +930,8 @@ class ServeEngine:
             step0 += self.chunk
             with stats.span("serve.harvest"):
                 harvest(ys)
+                if "counters" in ys:
+                    stats.counted("decode", ys["counters"], self.chunk)
 
         try:
             it = 0

@@ -36,7 +36,7 @@ from repro.configs.registry import get_config, reduced
 from repro.data.synthetic import TokenStreamState, token_batch
 
 # one representative arch per attention family (all four serve paths:
-# dense GQA, MoE with capacity routing, hybrid ssm+attention, encdec
+# dense GQA, dropless MoE, hybrid ssm+attention, encdec
 # cross-attention); dense additionally runs with the prefix cache on
 FAMILIES = {
     "dense": "qwen2_1_5b",

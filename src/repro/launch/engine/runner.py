@@ -154,30 +154,22 @@ class ModelRunner:
             # prompt's static attention-reduction extent) is static, so
             # compiles scale with distinct (chunk, span) pairs — bucketed
             # prompts collapse most of those (see prefill_compiles)
-            if cfg.family == "moe":
-                self._chunk_fn = self._jit(
-                    lambda p, t, c, s, o, n, off, span: M.prefill_chunk(
-                        p, cfg, t, c, s, o, n, span, expert_offsets=off),
-                    static_argnums=(7,), donate_argnums=(2,))
-            elif cfg.family == "hybrid":
+            if cfg.family == "hybrid":
                 self._chunk_fn = self._jit(
                     lambda p, t, c, s, o, n, st, span, fin:
                     M.prefill_chunk(p, cfg, t, c, s, o, n, span,
                                     state=st, finalize=fin),
                     static_argnums=(7, 8), donate_argnums=(2,))
-            elif cfg.family == "encdec":
-                self._chunk_first = self._jit(
-                    lambda p, t, c, s, o, n, fr, span: M.prefill_chunk(
-                        p, cfg, t, c, s, o, n, span, frames=fr),
-                    static_argnums=(7,), donate_argnums=(2,))
-                self._chunk_fn = self._jit(
-                    lambda p, t, c, s, o, n, span: M.prefill_chunk(
-                        p, cfg, t, c, s, o, n, span),
-                    static_argnums=(6,), donate_argnums=(2,))
             else:
+                if cfg.family == "encdec":
+                    self._chunk_first = self._jit(
+                        lambda p, t, c, s, o, n, fr, span: M.prefill_chunk(
+                            p, cfg, t, c, s, o, n, span, frames=fr),
+                        static_argnums=(7,), donate_argnums=(2,))
+                # -> (cache, the chunk's counters: {} outside moe)
                 self._chunk_fn = self._jit(
-                    lambda p, t, c, s, o, n, span: M.prefill_chunk(
-                        p, cfg, t, c, s, o, n, span),
+                    lambda p, t, c, s, o, n, span: M.take_counters(
+                        M.prefill_chunk(p, cfg, t, c, s, o, n, span)),
                     static_argnums=(6,), donate_argnums=(2,))
         self._suffix = self._copy = None
         if prefix_cache:

@@ -6,6 +6,12 @@ decode-attention HBM block tally, the downsampled scheduler trace —
 plus the span recorder the engine loop runs inside, and builds the
 results dict ``ServeEngine.run`` returns.
 
+Counters: ``counted`` keeps the per-call counters a program returns
+beside its outputs (the moe family's ``moe.rows`` and
+``moe.experts_hit``), as ``CountRecord``s; a decode chunk's come with
+the tokens the harvest already fetched, a prompt chunk's stay on the
+device until ``results``, so neither costs a device sync.
+
 Spans: ``span(name)`` marks one phase of the loop and ``iteration(i)``
 one pass of it.  Each enters a ``jax.profiler`` annotation (a host
 event on the device trace's clock whenever a trace is running; about
@@ -37,6 +43,20 @@ DECODE = ("serve.decode_dispatch", "serve.decode_wait",
 # iteration records kept: about 21 minutes of the ~310 ms iterations of
 # a 24-slot qwen2-1.5B engine on one TPU v5e, 4096 small tuples
 ITERATIONS_KEPT = 4096
+# program counter records kept (one per decode chunk and prompt chunk)
+COUNTED_KEPT = 2 * ITERATIONS_KEPT
+
+
+class CountRecord(NamedTuple):
+    """A program's counters for one call: the host time it was recorded
+    (decode: at its harvest; prefill: at its dispatch), ``kind``
+    ("decode" or "prefill"), the decode steps it ran (1 for a prompt
+    chunk), and each counter summed over them."""
+
+    t: float
+    kind: str
+    steps: int
+    values: dict
 
 
 class IterationRecord(NamedTuple):
@@ -102,6 +122,11 @@ class ServeStats:
             maxlen=ITERATIONS_KEPT)
         self._iter_wait = 0.0
         self._iter_rows = 0
+        # the programs' own counters (moe: ``moe.rows``,
+        # ``moe.experts_hit``), one record per call; a prompt chunk's
+        # values stay on the device until ``results`` reads them all
+        self.counts: collections.deque = collections.deque(
+            maxlen=COUNTED_KEPT)
         # the jitted prefill compiles once per distinct prompt length
         # (suffix prefill: per distinct suffix length); classify each
         # admission's time accordingly so mixed-length traffic doesn't
@@ -167,6 +192,12 @@ class ServeStats:
     def dispatched(self, rows: int) -> None:
         """The open iteration's decode dispatch serves ``rows`` slots."""
         self._iter_rows = rows
+
+    def counted(self, kind: str, counters: dict, steps: int) -> None:
+        """Record one program call's ``counters`` (name -> per-step
+        values, host or device) over ``steps`` decode steps."""
+        self.counts.append(CountRecord(time.perf_counter(), kind, steps,
+                                       counters))
 
     def _close(self, sp: Span) -> None:
         tot = self.phases.get(sp.name)
@@ -286,6 +317,14 @@ class ServeStats:
                 "preemptions": sum(r.preempt_count for r in group),
             }
         served = [it.t1 for it in self.iterations if it.rows]
+        # one transfer for every prompt chunk's device counters
+        counts = [r._replace(values={k: int(np.sum(v))
+                                     for k, v in vals.items()})
+                  for r, vals in zip(self.counts, jax.device_get(
+                      [r.values for r in self.counts]))]
+        totals: Counter = Counter()
+        for r in counts:
+            totals.update(r.values)
         epi = sum(r.epistemic_flags for r in requests)
         alea = sum(r.aleatoric_flags for r in requests)
         return {
@@ -415,6 +454,9 @@ class ServeStats:
                 "iterations": list(self.iterations),
                 "iterations_kept": self.iterations.maxlen,
             },
+            # the programs' counters: totals over the run, and the last
+            # COUNTED_KEPT per-call ``CountRecord``s
+            "counters": {"totals": dict(totals), "records": counts},
             "epistemic_flags": int(epi),
             "aleatoric_flags": int(alea),
             "flags_per_1k_tokens": {

@@ -162,15 +162,18 @@ def build_scan_decode(cfg: ArchConfig, entropy=None, chunk: int = 8,
     (token, cache, flags, ys)`` where the inner ``jax.lax.scan`` carries
     (token, slot-indexed cache, cumulative per-slot epistemic/aleatoric
     flag counters) and stacks per-step outputs ``ys`` = {token, H, SE,
-    MI, p_max, epistemic, aleatoric}, each (chunk, B).  No per-token
-    host sync: the caller transfers ``ys`` once per chunk.
+    MI, p_max, epistemic, aleatoric}, each (chunk, B), plus the family's
+    per-step ``counters`` (each (chunk,) int32; moe) where it has them.
+    No per-token host sync: the caller transfers ``ys`` once per chunk.
 
     ``active`` (B,) bool gates the carried counters: only occupied slots
     accumulate, so a pure-device driver can read per-slot flag totals
     without ever syncing ``ys``.  The counters are device telemetry: a
     request finishing mid-chunk keeps counting until the chunk boundary
     (the host can't evict inside the scan), so they upper-bound the
-    exact per-request host accounting done from ``ys``.
+    exact per-request host accounting done from ``ys``.  ``active`` is
+    also the decode body's ``rows``: a family that can (moe) spends no
+    routed-expert work on the other slots.
 
     Noise stream under scan: in operand mode the UNFOLDED base key is
     passed down every step and the models fold (slot, depth) into it
@@ -211,12 +214,15 @@ def build_scan_decode(cfg: ArchConfig, entropy=None, chunk: int = 8,
             tok, cache, epi, alea = carry
             key = jax.random.fold_in(base, step0 + t) if fold else base
             with jax.named_scope("decode_step"):
-                out, cache = M.decode_step(params, cfg, tok, cache, key)
+                out, cache = M.decode_step(params, cfg, tok, cache, key,
+                                           rows=active)
             is_epi = out["MI"] > mi_threshold
             is_alea = (out["SE"] > se_threshold) & ~is_epi
             ys = {"token": out["next_token"], "H": out["H"],
                   "SE": out["SE"], "MI": out["MI"], "p_max": out["p_max"],
                   "epistemic": is_epi, "aleatoric": is_alea}
+            if "counters" in out:
+                ys["counters"] = out["counters"]
             carry = (out["next_token"], cache,
                      epi + (is_epi & active).astype(jnp.int32),
                      alea + (is_alea & active).astype(jnp.int32))

@@ -1,274 +1,254 @@
 """Mixture-of-Experts transformer (grok-1-314b, deepseek-moe-16b).
 
-Routing: softmax top-k with capacity-based dispatch.  Token positions per
-expert come from a cumsum over the routing one-hot (no sort), tokens are
-scattered into an (E, C, d) buffer, experts run as one batched einsum, and
-the combine weights scatter results back.  Capacity overflow drops tokens
-(standard GShard semantics) — the capacity factor and the auxiliary
-load-balancing loss keep drops rare.
+Layer i < ``cfg.first_k_dense`` is a dense block (``d_ff`` SwiGLU); the
+others are MoE blocks:
 
-Expert parallelism: the (E, ...) expert weights shard over the ``model``
-mesh axis when ``expert_sharding == 'ep'`` (deepseek: 64 experts / 16-way
-model axis = 4 experts per group; the dispatch buffer's E axis is
-sharding-constrained so GSPMD inserts the dispatch/return all-to-alls).
-grok-1's 8 experts < 16-way axis, so it uses ``'tp'``: experts replicated
-over the axis with their ff dim tensor-parallel — no all-to-all, instead
-the usual TP reduce.
+    h = RMSNorm(x)
+    x += sum_shared FFN_s(h) + sum_{e in top_k(g), e held} g_e * FFN_e(h)
 
-DeepSeekMoE specifics (arXiv:2401.06066): fine-grained experts
-(moe_d_ff=1408 vs dense d_ff) + 2 shared experts always active.
+with ``g = softmax(h @ W_router)`` over all ``num_experts`` in float32,
+the top-k gates renormalised to sum 1 only where ``cfg.norm_topk``.
+
+Dropless: a token's output is a function of that token alone.  There
+is no capacity and no cross-token queue, so a chunk, a batch, a
+prefix-cache suffix or a decode step all give a token the same experts.
+
+A chip may hold a share of the routed experts (``MoEConfig.experts_held``
+from ``expert_offset``): the router still scores every expert, a token
+computes the part of its picks that this chip holds, and the shared
+experts whole.  Routed experts run as grouped matmuls
+(``kernels/moe_gmm.py``) on a TPU outside a mesh, and as the same math
+in plain ``jnp`` elsewhere (the CPU, a serving or training mesh).
+
+Parameters: ``blocks`` holds every layer's norms and attention (L
+stacked), ``dense`` the leading dense layers' MLPs, ``moe`` the MoE
+layers' router (Lm, d, E) float32, routed experts ``experts_ep`` or
+``experts_tp`` (``w13`` (Lm, held, d, 2 ff) gate then up, ``w2``) and
+the shared experts as one MLP of ``num_shared_experts * moe_d_ff``
+columns (their outputs sum, so they concatenate).  One scan walks all L
+layers; the feed-forward of layer i picks its kind with ``lax.cond``,
+so the KV cache keeps the one (L, ...) layout every family shares.
+
+Counters: the decode body and each prefill chunk return, under the
+cache key ``registry.COUNTERS``, ``moe.rows`` ((token, k) assignments
+routed to held experts) and ``moe.experts_hit`` (held experts that got
+a token), summed over the MoE layers.  Named scopes ``moe/router`` and
+``moe/experts``.
+
+Expert parallelism under a training mesh: the expert stacks shard over
+the ``model`` axis when ``expert_sharding == 'ep'``, or keep their ff
+dim tensor-parallel under ``'tp'`` (grok-1's 8 experts < 16-way axis).
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import MoEConfig
 from repro.models import layers as L
-from repro.models import uncertain_head as U
 from repro.models import transformer as T
+from repro.models import uncertain_head as U
 from repro.sharding.partition import constrain, constrain_seq
+
+COUNTERS = "counters"          # = registry.COUNTERS
 
 
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
 
-def _init_experts(key, cfg: ArchConfig, num: int, d_ff: int):
+def _experts_name(cfg: MoEConfig) -> str:
+    return "experts_ep" if cfg.expert_sharding == "ep" else "experts_tp"
+
+
+def _eff(cfg: MoEConfig) -> int:
+    return cfg.moe_d_ff or cfg.d_ff
+
+
+def init_attn_block(key, cfg: MoEConfig):
+    return {"ln1": jnp.ones((cfg.d_model,), L.dtype_of(cfg)),
+            "attn": L.init_attention(key, cfg),
+            "ln2": jnp.ones((cfg.d_model,), L.dtype_of(cfg))}
+
+
+def init_moe_layer(key, cfg: MoEConfig):
     dt = L.dtype_of(cfg)
-    d = cfg.d_model
-    ks = jax.random.split(key, 3)
-
-    def stack(k, shape, fan):
-        return (jax.random.normal(k, (num, *shape), jnp.float32)
-                / jnp.sqrt(float(fan))).astype(dt)
-
-    return {"w1": stack(ks[0], (d, d_ff), d),
-            "w3": stack(ks[1], (d, d_ff), d),
-            "w2": stack(ks[2], (d_ff, d), d_ff)}
-
-
-def init_block(key, cfg: ArchConfig):
+    d, eff, held = cfg.d_model, _eff(cfg), cfg.held
     k1, k2, k3, k4 = jax.random.split(key, 4)
-    eff = cfg.moe_d_ff or cfg.d_ff
-    ename = "experts_ep" if cfg.expert_sharding == "ep" else "experts_tp"
-    p = {
-        "ln1": jnp.ones((cfg.d_model,), L.dtype_of(cfg)),
-        "attn": L.init_attention(k1, cfg),
-        "ln2": jnp.ones((cfg.d_model,), L.dtype_of(cfg)),
-        "router": {"w": (jax.random.normal(
-            k2, (cfg.d_model, cfg.num_experts), jnp.float32) * 0.02)},
-        ename: _init_experts(k3, cfg, cfg.num_experts, eff),
-    }
+    p = {"router": {"w": jax.random.normal(
+            k1, (d, cfg.num_experts), jnp.float32) * 0.02},
+         _experts_name(cfg): {
+             "w13": L.he_init(k2, (held, d, 2 * eff), d, dt),
+             "w2": L.he_init(k3, (held, eff, d), eff, dt)}}
     if cfg.num_shared_experts:
-        p["shared"] = _init_experts(
-            k4, cfg, cfg.num_shared_experts, eff)
+        p["shared"] = L.init_mlp(k4, cfg,
+                                 d_ff=cfg.num_shared_experts * eff)
     return p
 
 
-def init_params(key, cfg: ArchConfig):
-    ke, kb, kh = jax.random.split(key, 3)
-    block_keys = jax.random.split(kb, cfg.num_layers)
-    blocks = jax.vmap(lambda k: init_block(k, cfg))(block_keys)
-    return {
-        "embed": L.init_embed(ke, cfg),
-        "blocks": blocks,
-        "final_norm": jnp.ones((cfg.d_model,), L.dtype_of(cfg)),
-        "head": L.init_head(kh, cfg),
-    }
+def init_params(key, cfg: MoEConfig):
+    ke, kb, kd, km, kh = jax.random.split(key, 5)
+    F = cfg.first_k_dense
+    p = {"embed": L.init_embed(ke, cfg),
+         "blocks": jax.vmap(lambda k: init_attn_block(k, cfg))(
+             jax.random.split(kb, cfg.num_layers)),
+         "moe": jax.vmap(lambda k: init_moe_layer(k, cfg))(
+             jax.random.split(km, cfg.moe_layers)),
+         "final_norm": jnp.ones((cfg.d_model,), L.dtype_of(cfg)),
+         "head": L.init_head(kh, cfg)}
+    if F:
+        p["dense"] = jax.vmap(lambda k: L.init_mlp(k, cfg))(
+            jax.random.split(kd, F))
+    return p
 
 
 # ---------------------------------------------------------------------------
-# MoE layer
+# the expert layer
 # ---------------------------------------------------------------------------
 
-def _expert_ffn(ep, cfg: ArchConfig, x: jax.Array) -> jax.Array:
-    """x: (..., E, C, d) -> (..., E, C, d), batched gated MLP over
-    experts (leading group axis broadcasts over the expert weights)."""
-    # partial-sum outputs in the activation dtype: the row-parallel (w2)
-    # all-reduce moves bf16 not f32 (§Perf/grok iteration 5)
-    g = jnp.einsum("...ecd,edf->...ecf", x, ep["w1"],
-                   preferred_element_type=x.dtype)
-    u = jnp.einsum("...ecd,edf->...ecf", x, ep["w3"],
-                   preferred_element_type=x.dtype)
-    h = jax.nn.silu(g) * u
-    return jnp.einsum("...ecf,efd->...ecd", h, ep["w2"],
-                      preferred_element_type=x.dtype)
+def _use_kernel() -> bool:
+    """Grouped-matmul kernels on a TPU outside any mesh; plain jnp on
+    the CPU and under a serving or training mesh (Pallas kernels are
+    not partitioned)."""
+    from repro.kernels.ops import _on_tpu
+    from repro.sharding.partition import get_mesh, get_serve_mesh
+    return _on_tpu() and get_mesh() is None and get_serve_mesh() is None
 
 
-def _dispatch_groups(cfg: ArchConfig, total_tokens: int) -> int:
-    """Number of dispatch groups = DP shards (GShard-style local
-    dispatch).  Tokens never leave their data shard for the capacity
-    buffer; only the expert einsum communicates (EP all-to-all or TP
-    reduce).  Without a mesh context (unit tests) this is 1 group --
-    identical semantics, global capacity.
-    """
-    from repro.sharding.partition import get_mesh
-    mesh = get_mesh()
-    if mesh is None:
-        return 1
-    g = 1
-    for a in ("pod", "data"):
-        if a in mesh.axis_names:
-            g *= mesh.shape[a]
-    while total_tokens % g and g > 1:       # safety for odd test shapes
-        g //= 2
-    return max(g, 1)
+def route(router_w, cfg: MoEConfig, h):
+    """(gates (T, E), top-k weights (T, K), top-k ids (T, K)) of the
+    normed hidden states h (T, d), all float32."""
+    gates = jax.nn.softmax(jnp.dot(h.astype(jnp.float32), router_w,
+                                   precision=jax.lax.Precision.HIGHEST),
+                           axis=-1)
+    topv, topi = jax.lax.top_k(gates, cfg.top_k)
+    if cfg.norm_topk:
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    return gates, topv, topi
 
 
-def moe_ffn(bp, cfg: ArchConfig, x: jax.Array,
-            expert_offsets: Optional[jax.Array] = None,
-            capacity: Optional[int] = None):
-    """x: (B, S, d) -> (y, aux_loss). Top-k capacity dispatch.
+def moe_ffn(mp, j, cfg: MoEConfig, x: jax.Array,
+            rows: Optional[jax.Array] = None):
+    """MoE layer ``j`` (of the stacks in ``mp``) over x (B, S, d), normed.
 
-    Grouped dispatch (perf iteration 1, EXPERIMENTS.md §Perf/grok):
-    tokens are dispatched into a (G, E, C, d) buffer whose group axis G
-    aligns with the DP sharding of the batch, so the scatter/gather is
-    LOCAL to each data shard (the naive global (E, C, d) buffer forced
-    GSPMD to all-reduce a replicated 32 GB scatter per layer).
-
-    ``expert_offsets`` (E,) f32 + ``capacity`` enable CHUNKED prefill:
-    the caller threads each expert's running assignment count across
-    chunks and fixes C to the value the full prompt would compute, so a
-    token's keep/drop decision is made against its GLOBAL queue position
-    — identical to the one batch dispatch over the whole prompt (counts
-    are small integers, exact in f32).  When set, the return gains a
-    third element: the updated offsets (counts include dropped
-    assignments, matching the batch cumsum).  G must be 1 in this mode.
-    """
+    ``rows`` (B, S) bool, optional: tokens that route to no expert (idle
+    decode slots, a chunk's pad), so that they cost no expert work and
+    no count.  Returns (y (B, S, d), counts (2,) int32 = [assignments to
+    held experts, held experts hit], the Switch load-balance loss)."""
+    from repro.kernels.moe_gmm import grouped_ffn_ref, moe_gmm
     B, S, d = x.shape
-    Tn = B * S
-    E, K = cfg.num_experts, cfg.top_k
-    G = _dispatch_groups(cfg, Tn) if expert_offsets is None else 1
-    Tg = Tn // G
-    C = capacity if capacity is not None else \
-        max(int(Tg * K / E * cfg.capacity_factor), 8)
-    xg = x.reshape(G, Tg, d)                                   # B-major
-    xg = constrain(xg, "batch", None, None)
-
-    gate_logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
-                             bp["router"]["w"])
-    gates = jax.nn.softmax(gate_logits, axis=-1)               # (G, T, E)
-    topv, topi = jax.lax.top_k(gates, K)                       # (G, T, K)
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-
-    # load-balancing aux loss (Switch): E * sum_e f_e * p_e
-    onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)        # (G,T,K,E)
-    f = onehot.sum(2).mean(1)                                  # (G, E)
-    p = gates.mean(1)
-    aux = E * jnp.sum(f * p, axis=-1).mean()
-
-    # position of each (token, k) within its expert queue, per group
-    oh_flat = onehot.reshape(G, Tg * K, E)
-    pos = jnp.sum((jnp.cumsum(oh_flat, axis=1) - 1.0) * oh_flat,
-                  axis=-1).reshape(G, Tg, K)
-    if expert_offsets is None:
-        keep = pos < C                                         # capacity
-    else:
-        # global queue position = carried count + local position; the
-        # local position still indexes the scatter buffer (it is < C
-        # whenever keep, since offsets >= 0)
-        keep = (pos + expert_offsets[topi]) < C
-    eid = topi.reshape(G, Tg * K)
-    cid = jnp.where(keep, pos, C).reshape(G, Tg * K).astype(jnp.int32)
-
-    # per-group local scatter into (E, C+1, d); slot C = overflow bin
-    def scatter_group(xt, e, c):
-        tok_rep = jnp.repeat(xt, K, axis=0)                    # (T*K, d)
-        return jnp.zeros((E, C + 1, d), x.dtype).at[e, c].add(tok_rep)
-
-    buf = jax.vmap(scatter_group)(xg, eid, cid)                # (G,E,C+1,d)
-    buf = constrain(buf, "batch",
-                    "model" if cfg.expert_sharding == "ep" else None,
-                    None, None)
-    ep = bp["experts_ep"] if "experts_ep" in bp else bp["experts_tp"]
-    out_buf = _expert_ffn(ep, cfg, buf[:, :, :C])
-    out_buf = constrain(out_buf, "batch",
-                        "model" if cfg.expert_sharding == "ep" else None,
-                        None, None)
-    out_buf = jnp.pad(out_buf, ((0, 0), (0, 0), (0, 1), (0, 0)))
-
-    # gather back with combine weights, per group
-    def gather_group(ob, e, c, w):
-        y = ob[e, c]                                           # (T*K, d)
-        return (y * w[:, None]).reshape(Tg, K, d).sum(1)
-
-    w = (topv.reshape(G, Tg * K)
-         * keep.reshape(G, Tg * K)).astype(x.dtype)
-    y = jax.vmap(gather_group)(out_buf, eid, cid, w)           # (G, Tg, d)
-
-    if cfg.num_shared_experts:
-        sh = _expert_ffn(bp["shared"], cfg,
-                         jnp.broadcast_to(
-                             xg.reshape(1, Tn, d),
-                             (cfg.num_shared_experts, Tn, d)))
-        y = y + sh.sum(0).reshape(G, Tg, d)
-    if expert_offsets is not None:
-        return (y.reshape(B, S, d), aux,
-                expert_offsets + oh_flat[0].sum(axis=0))
-    return y.reshape(B, S, d), aux
+    E, held = cfg.num_experts, cfg.held
+    xt = x.reshape(B * S, d)
+    at = lambda a: jax.lax.dynamic_index_in_dim(a, j, 0,  # noqa: E731
+                                                keepdims=False)
+    with jax.named_scope("moe/router"):
+        gates, topv, topi = route(at(mp["router"]["w"]), cfg, xt)
+        local = topi - cfg.expert_offset
+        mine = (local >= 0) & (local < held)
+        if rows is not None:
+            mine &= rows.reshape(B * S, 1)
+        experts = jnp.where(mine, local, held).astype(jnp.int32)
+        weights = jnp.where(mine, topv, 0.0)
+        hits = jnp.zeros((held + 1,), jnp.int32).at[experts].add(1)
+        counts = jnp.stack([mine.sum(dtype=jnp.int32),
+                            (hits[:held] > 0).sum(dtype=jnp.int32)])
+        # Switch load-balance loss, E * sum_e f_e p_e (training aux)
+        f = jax.nn.one_hot(topi, E, dtype=jnp.float32).sum(1).mean(0)
+        aux = E * jnp.sum(f * gates.mean(0))
+    with jax.named_scope("moe/experts"):
+        ep = mp[_experts_name(cfg)]
+        args = (xt, experts, weights, ep["w13"], ep["w2"], j)
+        if _use_kernel():
+            from repro.kernels.ops import _on_tpu
+            y = moe_gmm(*args, act=cfg.mlp_activation,
+                        interpret=not _on_tpu())
+        else:
+            y = grouped_ffn_ref(*args, act=cfg.mlp_activation)
+        if "shared" in mp:
+            y = y + L.apply_mlp(jax.tree.map(at, mp["shared"]), cfg,
+                                xt).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(B, S, d), counts, aux
 
 
-def _block_fwd(bp, cfg: ArchConfig, x, positions):
-    # sequence-parallel residual stream (see models/transformer.py)
-    h, _ = L.apply_attention(bp["attn"], cfg, L.rms_norm(x, bp["ln1"]),
-                             positions=positions, causal=True)
-    x = x + constrain_seq(h, cfg.seq_parallel)
-    x = constrain_seq(x, cfg.seq_parallel)
-    y, aux = moe_ffn(bp, cfg, L.rms_norm(x, bp["ln2"]))
-    x = x + constrain_seq(y, cfg.seq_parallel)
-    x = constrain_seq(x, cfg.seq_parallel)
-    return x, aux
+def _ffn(params, cfg: MoEConfig, i, x, rows):
+    """Layer i's feed-forward over normed x: the dense MLP for
+    i < first_k_dense, else the MoE layer i - first_k_dense."""
+    F = cfg.first_k_dense
+
+    def routed(x):
+        return moe_ffn(params["moe"], jnp.maximum(i - F, 0), cfg, x, rows)
+
+    if not F:
+        return routed(x)
+
+    def dense(x):
+        mp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, jnp.minimum(i, F - 1), 0, keepdims=False), params["dense"])
+        with jax.named_scope("mlp"):
+            y = L.apply_mlp(mp, cfg, x)
+        return y, jnp.zeros((2,), jnp.int32), jnp.zeros((), jnp.float32)
+
+    return jax.lax.cond(i < F, dense, routed, x)
 
 
-def forward(params, cfg: ArchConfig, tokens: jax.Array,
-            prefix_embeds: Optional[jax.Array] = None):
+def _walk(params, cfg: MoEConfig, x, attend, xs, rows=None,
+          seq_parallel=False, remat=False):
+    """Every layer over x: ``attend(block_params, normed_x, xs_i) ->
+    (out, ys_i)`` gives the attention, then the layer's feed-forward.
+    Returns (x, stacked ys, counts summed over layers, mean aux)."""
+    eps = cfg.norm_eps
+
+    def layer(x, inp):
+        i, bp, xi = inp
+        h, yi = attend(bp, L.rms_norm(x, bp["ln1"], eps), xi)
+        x = constrain_seq(x + constrain_seq(h, seq_parallel), seq_parallel)
+        y, counts, aux = _ffn(params, cfg, i, L.rms_norm(x, bp["ln2"], eps),
+                              rows)
+        x = constrain_seq(x + constrain_seq(y, seq_parallel), seq_parallel)
+        return x, (yi, counts, aux)
+
+    if remat:
+        layer = jax.checkpoint(layer, prevent_cse=False)
+    idx = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    x, (ys, counts, aux) = jax.lax.scan(layer, x,
+                                        (idx, params["blocks"], xs))
+    return x, ys, counts.sum(0), aux.sum() / max(cfg.moe_layers, 1)
+
+
+def _counters(counts) -> dict:
+    return {"moe.rows": counts[0], "moe.experts_hit": counts[1]}
+
+
+# ---------------------------------------------------------------------------
+# training forward
+# ---------------------------------------------------------------------------
+
+def forward(params, cfg: MoEConfig, tokens: jax.Array,
+            return_kv: bool = False):
+    """tokens (B, S) -> (hidden (B, S, d), aux, per-layer (k, v) or None)."""
     x = L.apply_embed(params["embed"], tokens)
     x = constrain(x, "batch", None, None)
     x = constrain_seq(x, cfg.seq_parallel)
     positions = jnp.arange(tokens.shape[1])[None, :]
 
-    def scan_step(carry, bp):
-        x = carry
-        if cfg.remat:
-            y, aux = jax.checkpoint(
-                lambda b, xx: _block_fwd(b, cfg, xx, positions),
-                prevent_cse=False)(bp, x)
-        else:
-            y, aux = _block_fwd(bp, cfg, x, positions)
-        return y, aux
+    def attend(bp, h, _):
+        h, kv = L.apply_attention(bp["attn"], cfg, h, positions=positions,
+                                  causal=True)
+        return h, (kv if return_kv else None)
 
-    g = cfg.remat_group
-    if cfg.scan_layers and cfg.remat and g and cfg.num_layers % g == 0:
-        # hierarchical remat (see models/transformer.py)
-        grouped = jax.tree.map(
-            lambda a: a.reshape(cfg.num_layers // g, g, *a.shape[1:]),
-            params["blocks"])
-
-        def outer_step(x, bps):
-            def inner(xx, bp):
-                y, aux = _block_fwd(bp, cfg, xx, positions)
-                return y, aux
-
-            y, auxes = jax.checkpoint(
-                lambda b, xx: jax.lax.scan(inner, xx, b),
-                prevent_cse=False)(bps, x)
-            return y, auxes.mean()
-
-        x, auxes = jax.lax.scan(outer_step, x, grouped)
-    else:
-        x, auxes = jax.lax.scan(scan_step, x, params["blocks"])
+    x, kvs, _, aux = _walk(params, cfg, x, attend, None,
+                           seq_parallel=cfg.seq_parallel,
+                           remat=cfg.remat and not return_kv)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x, auxes.mean()
+    return x, aux, kvs
 
 
-def nll_loss(params, cfg: ArchConfig, batch: dict, key: jax.Array,
+def nll_loss(params, cfg: MoEConfig, batch: dict, key: jax.Array,
              aux_weight: float = 0.01):
-    hidden, aux = forward(params, cfg, batch["tokens"])
+    hidden, aux, _ = forward(params, cfg, batch["tokens"])
     head = params["head"]
     if "q" in head:
         eps = jax.random.normal(key, head["q"].mu.shape, jnp.float32)
@@ -292,96 +272,101 @@ def nll_loss(params, cfg: ArchConfig, batch: dict, key: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# serving (decode with per-token MoE routing)
+# serving
 # ---------------------------------------------------------------------------
 
 make_cache = T.make_cache  # same KV cache layout
 
 
-def prefill(params, cfg: ArchConfig, tokens: jax.Array, max_len: int):
-    """MoE prefill: rerun forward collecting kv (same trick as dense)."""
-    x = L.apply_embed(params["embed"], tokens)
-    positions = jnp.arange(tokens.shape[1])[None, :]
-
-    def scan_step(x, bp):
-        h, kv = L.apply_attention(bp["attn"], cfg,
-                                  L.rms_norm(x, bp["ln1"]),
-                                  positions=positions, causal=True)
-        x = x + h
-        y, _ = moe_ffn(bp, cfg, L.rms_norm(x, bp["ln2"]))
-        return x + y, kv
-
-    x, kvs = jax.lax.scan(scan_step, x, params["blocks"])
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+def prefill(params, cfg: MoEConfig, tokens: jax.Array, max_len: int):
+    """Run the full prompt, build the KV cache: (hidden_last, cache)."""
+    x, _, (k, v) = forward(params, cfg, tokens, return_kv=True)
     S = tokens.shape[1]
-    k, v = kvs
-    pad = max_len - S
-    k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    return x[:, -1], {"k": k, "v": v,
+    pad = ((0, 0), (0, 0), (0, max_len - S), (0, 0), (0, 0))
+    return x[:, -1], {"k": jnp.pad(k, pad), "v": jnp.pad(v, pad),
                       "len": jnp.full((tokens.shape[0],), S, jnp.int32)}
 
 
-def prefill_chunk(params, cfg: ArchConfig, tokens: jax.Array, cache: dict,
-                  slot: jax.Array, offset: jax.Array, new_len: jax.Array,
-                  span: int, expert_offsets: jax.Array):
-    """Chunked MoE prefill step (see transformer.prefill_chunk).
-
-    ``expert_offsets``: (L, E) f32 per-layer running expert assignment
-    counts, threaded by the engine across chunks so capacity drops match
-    the single batch dispatch bit for bit; the capacity itself is pinned
-    to what the full ``span``-token prompt computes.  Returns
-    (cache, new_expert_offsets)."""
-    E, K = cfg.num_experts, cfg.top_k
-    C = max(int(span * K / E * cfg.capacity_factor), 8)
-    row = jax.lax.dynamic_slice_in_dim(cache["block_table"], slot, 1, 0)
+def prefill_suffix(params, cfg: MoEConfig, tokens: jax.Array,
+                   prefix_kv: dict, prefix_len: int):
+    """Prefill ONLY the uncached suffix of a prefix-cache hit (see
+    ``transformer.prefill_suffix``): the attention path is the cold
+    prefill's, and the dropless expert layer gives each suffix token
+    what the cold prompt gives it."""
+    prefix_len = int(prefix_len)
     x = L.apply_embed(params["embed"], tokens)
+    S = tokens.shape[1]
+    positions = prefix_len + jnp.arange(S)[None, :]
+    strips = {n: prefix_kv[n][:, :, :prefix_len] for n in ("k", "v")}
 
-    def scan_step(x, bpkv):
-        bp, kp, vp, off = bpkv
-        h, (kp, vp) = L.apply_attention_chunk(
-            bp["attn"], cfg, L.rms_norm(x, bp["ln1"]),
-            kv_pools=(kp, vp), block_row=row, offset=offset, span=span)
-        x = x + h
-        y, _, off2 = moe_ffn(bp, cfg, L.rms_norm(x, bp["ln2"]),
-                             expert_offsets=off, capacity=C)
-        return x + y, (kp, vp, off2)
+    def attend(bp, h, pkv):
+        return L.apply_attention_suffix(
+            bp["attn"], cfg, h, prefix_kv=(pkv["k"], pkv["v"]),
+            prefix_len=prefix_len, positions=positions)
 
-    _, (kps, vps, offs) = jax.lax.scan(
-        scan_step, x,
-        (params["blocks"], cache["k"], cache["v"], expert_offsets))
-    cache = dict(cache, k=kps, v=vps,
-                 len=cache["len"].at[slot].set(new_len))
-    return cache, offs
-
-
-def decode_hidden(params, cfg: ArchConfig, token: jax.Array, cache: dict):
-    """The KV-writing decode body (see transformer.decode_hidden)."""
-    x = L.apply_embed(params["embed"], token[:, None])
-    cache_len = cache["len"]
-    block_table = cache.get("block_table")     # paged layout marker
-    # (read path per cfg.decode_attn: gather or block-sparse kernel)
-
-    def scan_step(x, bpkv):
-        bp, kv = bpkv
-        pos = jnp.reshape(cache_len, (-1, 1))
-        h, new_kv = L.apply_attention(
-            bp["attn"], cfg, L.rms_norm(x, bp["ln1"]), positions=pos,
-            kv_cache=(kv["k"], kv["v"]), cache_len=cache_len,
-            block_table=block_table)
-        x = x + h
-        y, _ = moe_ffn(bp, cfg, L.rms_norm(x, bp["ln2"]))
-        return x + y, {"k": new_kv[0], "v": new_kv[1]}
-
-    x, new_kvs = jax.lax.scan(
-        scan_step, x, (params["blocks"], {"k": cache["k"], "v": cache["v"]}))
+    x, (k, v), _, _ = _walk(params, cfg, x, attend, strips)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return x[:, 0], {"k": new_kvs["k"], "v": new_kvs["v"],
-                     "len": cache_len + 1}
+    lens = jnp.full((tokens.shape[0],), prefix_len + S, jnp.int32)
+    return x[:, -1], {"k": k, "v": v, "len": lens}
 
 
-def decode_step(params, cfg: ArchConfig, token: jax.Array, cache: dict,
-                key: jax.Array):
-    hidden, new_cache = decode_hidden(params, cfg, token, cache)
+def prefill_chunk(params, cfg: MoEConfig, tokens: jax.Array, cache: dict,
+                  slot: jax.Array, offset: jax.Array, new_len: jax.Array,
+                  span: int):
+    """One chunk of an incremental prompt prefill (see
+    ``transformer.prefill_chunk``).  The pad past ``new_len`` routes to
+    no expert.  The cache comes back with the chunk's counters under
+    ``COUNTERS``.  Named scope ``prefill_chunk``."""
+    with jax.named_scope("prefill_chunk"):
+        row = jax.lax.dynamic_slice_in_dim(cache["block_table"], slot, 1, 0)
+        x = L.apply_embed(params["embed"], tokens)
+        real = offset + jnp.arange(tokens.shape[1])[None, :] < new_len
+
+        def attend(bp, h, kv):
+            return L.apply_attention_chunk(
+                bp["attn"], cfg, h, kv_pools=kv, block_row=row,
+                offset=offset, span=span)
+
+        _, (kps, vps), counts, _ = _walk(params, cfg, x, attend,
+                                         (cache["k"], cache["v"]), real)
+        return dict(cache, k=kps, v=vps,
+                    len=cache["len"].at[slot].set(new_len),
+                    **{COUNTERS: _counters(counts)})
+
+
+def decode_hidden(params, cfg: MoEConfig, token: jax.Array, cache: dict,
+                  rows: Optional[jax.Array] = None):
+    """The KV-writing decode body (see ``transformer.decode_hidden``).
+
+    ``rows`` (B,) bool, optional: the slots whose tokens are real; the
+    others route to no expert.  The new cache carries the step's
+    counters under ``COUNTERS``.  Named scope ``decode_body`` (blocks:
+    ``attention``, ``mlp`` for a dense layer, ``moe/router`` and
+    ``moe/experts``)."""
+    with jax.named_scope("decode_body"):
+        x = L.apply_embed(params["embed"], token[:, None])
+        cache_len = cache["len"]
+        pos = jnp.reshape(cache_len, (-1, 1))
+        block_table = cache.get("block_table")     # paged layout marker
+
+        def attend(bp, h, kv):
+            with jax.named_scope("attention"):
+                h, new_kv = L.apply_attention(
+                    bp["attn"], cfg, h, positions=pos,
+                    kv_cache=(kv["k"], kv["v"]), cache_len=cache_len,
+                    block_table=block_table)
+            return h, {"k": new_kv[0], "v": new_kv[1]}
+
+        x, new_kvs, counts, _ = _walk(
+            params, cfg, x, attend, {"k": cache["k"], "v": cache["v"]},
+            None if rows is None else rows[:, None])
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        return x[:, 0], {"k": new_kvs["k"], "v": new_kvs["v"],
+                         "len": cache_len + 1, COUNTERS: _counters(counts)}
+
+
+def decode_step(params, cfg: MoEConfig, token: jax.Array, cache: dict,
+                key: jax.Array, rows: Optional[jax.Array] = None):
+    hidden, new_cache = decode_hidden(params, cfg, token, cache, rows)
     return U.head_outputs(params, cfg, hidden, cache["len"], key), \
         new_cache

@@ -139,10 +139,11 @@ def prefill_chunk(params, cfg: ArchConfig, tokens, cache, slot, offset,
 
     tokens: (1, S) chunk at absolute positions ``offset + [0, S)``;
     ``span``: static attention-reduction extent of the whole prompt.
-    Family-specific keywords: ``expert_offsets`` (moe, returns
-    ``(cache, new_offsets)``), ``state``/``finalize`` (hybrid, returns
-    ``(cache, new_state)``), ``frames`` (encdec first chunk).  See
-    ``supports_chunked_prefill`` for the dispatch gate."""
+    Family-specific keywords: ``state``/``finalize`` (hybrid, returns
+    ``(cache, new_state)``), ``frames`` (encdec first chunk).  A family
+    may return counters in the cache under ``COUNTERS``
+    (``take_counters``).  See ``supports_chunked_prefill`` for the
+    dispatch gate."""
     if not supports_chunked_prefill(cfg):
         raise ValueError(f"family {cfg.family!r} has no chunked prefill")
     return module_for(cfg).prefill_chunk(params, cfg, tokens, cache,
@@ -155,14 +156,13 @@ def supports_prefix_cache(cfg: ArchConfig) -> bool:
 
     Sound only when per-position prompt state is a pure function of the
     token prefix: ``vlm``/``encdec``/``audio`` mix non-token modality
-    inputs (prefix embeds, encoder frames) into the cache, ``ssm``/
+    inputs (prefix embeds, encoder frames) into the cache, and ``ssm``/
     ``hybrid`` carry recurrent state that a KV-block prefix cannot
-    reconstruct, and ``moe`` couples tokens through the expert-capacity
-    cumsum (a suffix-only prefill sees a different contention set, so
-    capacity drops — and therefore bits — can differ).  That leaves the
-    dense token-only family.
+    reconstruct.  That leaves the token-only families: ``dense`` and
+    ``moe``, whose dropless expert layer gives a token the same experts
+    whatever else shares its batch.
     """
-    return cfg.family == "dense"
+    return cfg.family in ("dense", "moe")
 
 
 def prefill_suffix(params, cfg: ArchConfig, tokens, prefix_kv: dict,
@@ -202,9 +202,41 @@ def prefill(params, cfg: ArchConfig, tokens, max_len: int,
     return mod.prefill(params, cfg, tokens, max_len)
 
 
-def decode_step(params, cfg: ArchConfig, token, cache, key):
+# a family's decode body or prefill chunk may return small per-call
+# int32 counters in its new cache under this key (moe: ``moe.rows``,
+# ``moe.experts_hit``); ``take_counters`` moves them out, so the cache
+# keeps the structure a scan carries
+COUNTERS = "counters"
+
+# families whose decode body takes ``rows``: the slots whose tokens are
+# real, so that the others cost no routed-expert work
+_ROW_MASKED = ("moe",)
+
+
+def take_counters(cache) -> tuple[Any, dict]:
+    """(cache without ``COUNTERS``, the counters or {})."""
+    if not isinstance(cache, dict) or COUNTERS not in cache:
+        return cache, {}
+    cache = dict(cache)
+    return cache, cache.pop(COUNTERS)
+
+
+def _rows_kw(cfg: ArchConfig, rows) -> dict:
+    return {"rows": rows} if rows is not None \
+        and cfg.family in _ROW_MASKED else {}
+
+
+def decode_step(params, cfg: ArchConfig, token, cache, key, rows=None):
+    """One decode step: ``(outputs, new_cache)``.  ``rows`` (B,) bool,
+    optional, marks the slots whose tokens are real (see
+    ``_ROW_MASKED``); a family's counters come back as
+    ``outputs["counters"]``."""
     out, new_cache = module_for(cfg).decode_step(params, cfg, token,
-                                                 cache, key)
+                                                 cache, key,
+                                                 **_rows_kw(cfg, rows))
+    new_cache, counters = take_counters(new_cache)
+    if counters:
+        out = dict(out, counters=counters)
     # reattach the paged block table centrally so the scan carry keeps
     # its structure without every family hand-copying it
     if isinstance(cache, dict) and "block_table" in cache:
@@ -222,6 +254,7 @@ def decode_hidden(params, cfg: ArchConfig, token, cache):
     re-runs only the head over the stacked draft hiddens)."""
     hidden, new_cache = module_for(cfg).decode_hidden(params, cfg, token,
                                                       cache)
+    new_cache, _ = take_counters(new_cache)
     if isinstance(cache, dict) and "block_table" in cache:
         new_cache.setdefault("block_table", cache["block_table"])
     return hidden, new_cache
@@ -243,12 +276,10 @@ def supports_spec_decode(cfg: ArchConfig) -> bool:
 
     Every family exposes the ``decode_hidden``/``head_outputs`` split,
     so all of them speculate.  Losslessness rests on per-slot decode
-    state being independent across slots given the fed tokens; the one
-    cross-slot coupling in the zoo is MoE's capacity cumsum, which only
-    bites when an expert overflows during single-token decode dispatch
-    — never hit on the served configs (the same assumption the PR 2
-    scan-vs-reference parity already makes), and the bitwise parity
-    harness (tests/test_spec_decode.py) would catch it if it were.
+    state being independent across slots given the fed tokens, which
+    holds in every family: MoE's expert layer is dropless, so a token's
+    experts never depend on the other slots (the bitwise parity harness,
+    tests/test_spec_decode.py, checks it).
     """
     return True
 

@@ -62,7 +62,8 @@ def _head_outputs(params, cfg, hidden, cache_len, key, num_samples):
     head = params["head"]
     S = cfg.mc_samples if num_samples is None else num_samples
     transformer = cfg.family in ("dense", "vlm")
-    if transformer and num_samples is None and "q" in head \
+    if cfg.family in ("dense", "vlm", "moe") and num_samples is None \
+            and "q" in head \
             and not cfg.logits_softcap and cfg.head_entropy == "kernel":
         # seed-driven fused head: on TPU the xi tensor never exists (the
         # uncertainty-head kernel draws it in-register and regenerates

@@ -224,7 +224,7 @@ class TestSuffixPrefill:
                 np.asarray(sub[name]))
 
     def test_unsupported_family_raises(self):
-        cfg = reduced(get_config("deepseek_moe_16b"))
+        cfg = reduced(get_config("zamba2_7b"))
         with pytest.raises(ValueError, match="cannot prefix-share"):
             M.prefill_suffix(None, cfg, None, None, 0)
 
@@ -316,10 +316,11 @@ class TestPrefixEngine:
                         kv_layout="dense", prefix_cache=True)
 
     def test_unsupported_family_serves_cold(self):
-        """moe prompt KV is not a pure function of the token prefix
-        (capacity cumsum couples tokens), so the engine silently serves
-        it cold — same fallback convention as ssm's dense layout."""
-        cfg = dataclasses.replace(reduced(get_config("deepseek_moe_16b")),
+        """hybrid prompt state is not a pure function of the KV blocks
+        (its SSM/conv recurrent state is not paged), so the engine
+        silently serves it cold — same fallback convention as ssm's
+        dense layout."""
+        cfg = dataclasses.replace(reduced(get_config("zamba2_7b")),
                                   head_entropy="operand")
         params = M.init_params(jax.random.key(1), cfg)
         engine = ServeEngine(params, cfg, num_slots=2, max_len=24,
